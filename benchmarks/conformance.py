@@ -9,8 +9,9 @@ the command-line form of the guarantee in DESIGN.md §10.
 ``--json [PATH]`` additionally writes a machine-readable summary (default
 ``BENCH_conformance.json``, same top-level shape as ``BENCH_sweep.json``:
 a ``benchmarks`` timing block, plus the per-record rows) so future PRs can
-diff the measured trajectory.  ``--execute`` also runs the kernels in
-interpret mode against the jnp oracle (slower; compile-only by default).
+diff the measured trajectory.  ``--execute`` also runs the kernels against
+the jnp oracle (compile-only by default): compiled on a TPU, in Pallas
+interpret mode on the CPU (:func:`repro.kernels.resolve_interpret`).
 ``--points M`` truncates the sweep for smoke runs.
 """
 
@@ -37,8 +38,9 @@ def main(argv=None) -> int:
     ap.add_argument("--points", type=int, default=None, metavar="M",
                     help="truncate the operating-point sweep to M points")
     ap.add_argument("--execute", action="store_true",
-                    help="also execute the kernels (interpret mode) against "
-                         "the jnp oracle at each point")
+                    help="also execute the kernels (compiled on a TPU, "
+                         "interpreted on the CPU) against the jnp oracle at "
+                         "each point")
     args = ap.parse_args(argv)
 
     from repro.core.conformance import (default_operating_points,
@@ -107,4 +109,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

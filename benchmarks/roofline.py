@@ -64,4 +64,7 @@ def render_table(mesh: str = "single") -> str:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print(render_table())

@@ -11,6 +11,8 @@ the inter-phase buffer the fused kernel eliminates.
     PYTHONPATH=src python examples/conformance_walkthrough.py
 """
 
+import jax
+
 from repro.core import registry
 from repro.core.conformance import (OperatingPoint, conformance_records,
                                     interphase_delta_records,
@@ -61,7 +63,7 @@ def main() -> None:
                   f"measured delta={r.measured_bytes:8.0f}B ratio={r.ratio:.4f}")
 
     print("\nexecuting both kernels once against the jnp oracle "
-          "(interpret mode):")
+          f"(on {jax.default_backend()}; interpreted off the TPU):")
     err = verify_numerics(points[0])
     print(f"  max relative error = {err:.3e}")
 

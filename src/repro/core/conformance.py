@@ -28,10 +28,12 @@ Measurement layers (each a ``ConformanceRecord.source``):
     unfused boundary delta measures exactly the paper's eliminated
     ``K*N*sigma + P_s*N*sigma`` terms.
 ``cost_analysis``
-    ``compiled.cost_analysis()['bytes accessed']``.  On CPU the
-    ``interpret=True`` lowering adds loop-machinery traffic, so this is
-    asserted as a one-sided floor (measured >= boundary), not an equality;
-    on a real TPU backend the same record tightens.
+    ``compiled.cost_analysis()['bytes accessed']``, asserted as a one-sided
+    floor (measured >= boundary), not an equality, on every backend.  On
+    the CPU the kernels run in Pallas interpret mode, whose lowering adds
+    loop-machinery traffic; compiled for a TPU (``interpret=None`` there,
+    see :func:`repro.kernels.resolve_interpret`) the kernel is one
+    ``tpu_custom_call`` and the record sits just above the boundary.
 ``hlo_collectives``
     Wire bytes from :func:`~repro.core.hlo_analysis.parse_collectives` —
     zero for these single-device programs, and the hook through which the
@@ -54,7 +56,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass
-from typing import Callable, Iterable, Mapping, Sequence
+from typing import Callable, Iterable, Mapping, Optional, Sequence
 
 import numpy as np
 
@@ -272,7 +274,8 @@ class _SpMMAnalogueBase:
         return jax.ShapeDtypeStruct(shape, jnp.float32)
 
     def programs(self, point: OperatingPoint, *,
-                 interpret: bool = True) -> tuple[ProgramMeasurement, ...]:
+                 interpret: Optional[bool] = None
+                 ) -> tuple[ProgramMeasurement, ...]:
         raise NotImplementedError
 
 
@@ -282,7 +285,8 @@ class FusedSpMMAnalogue(_SpMMAnalogueBase):
     dataflow = "spmm_tiled"
 
     def programs(self, point: OperatingPoint, *,
-                 interpret: bool = True) -> tuple[ProgramMeasurement, ...]:
+                 interpret: Optional[bool] = None
+                 ) -> tuple[ProgramMeasurement, ...]:
         from ..kernels import edge_aggregate as ea
         K, N, T = point.K, point.N, point.T
         compiled = self._compile(
@@ -302,7 +306,8 @@ class UnfusedSpMMAnalogue(_SpMMAnalogueBase):
     dataflow = "spmm_unfused"
 
     def programs(self, point: OperatingPoint, *,
-                 interpret: bool = True) -> tuple[ProgramMeasurement, ...]:
+                 interpret: Optional[bool] = None
+                 ) -> tuple[ProgramMeasurement, ...]:
         from ..kernels import edge_aggregate_unfused as eu
         K, N, T = point.K, point.N, point.T
         agg = self._compile(
@@ -325,7 +330,7 @@ class UnfusedSpMMAnalogue(_SpMMAnalogueBase):
 
 
 def measure_analogue(analogue, point: OperatingPoint, *,
-                     interpret: bool = True) -> list[dict]:
+                     interpret: Optional[bool] = None) -> list[dict]:
     """Compile + measure every program of one analogue at one point.
     Compilation dominates the sweep cost — callers sharing a point should
     measure once and pass the result to the record builders."""
@@ -334,7 +339,7 @@ def measure_analogue(analogue, point: OperatingPoint, *,
 
 
 def conformance_records(spec: DataflowSpec, point: OperatingPoint, *,
-                        interpret: bool = True, analogue=None,
+                        interpret: Optional[bool] = None, analogue=None,
                         measures: list[dict] | None = None
                         ) -> list[ConformanceRecord]:
     """All conformance records of one dataflow at one operating point."""
@@ -394,7 +399,8 @@ def conformance_records(spec: DataflowSpec, point: OperatingPoint, *,
     return records
 
 
-def interphase_delta_records(point: OperatingPoint, *, interpret: bool = True,
+def interphase_delta_records(point: OperatingPoint, *,
+                             interpret: Optional[bool] = None,
                              fused_measures: list[dict] | None = None,
                              unfused_measures: list[dict] | None = None
                              ) -> list[ConformanceRecord]:
@@ -445,7 +451,7 @@ def interphase_delta_records(point: OperatingPoint, *, interpret: bool = True,
 
 def run_conformance(names: Iterable[str] | None = None,
                     points: Sequence[OperatingPoint] | None = None, *,
-                    interpret: bool = True,
+                    interpret: Optional[bool] = None,
                     include_delta: bool = True,
                     preflight_audit: bool = True) -> list[ConformanceRecord]:
     """The full harness: every runnable dataflow x every operating point.
@@ -494,7 +500,7 @@ def run_conformance(names: Iterable[str] | None = None,
 
 
 def verify_numerics(point: OperatingPoint, *, seed: int = 0,
-                    interpret: bool = True) -> float:
+                    interpret: Optional[bool] = None) -> float:
     """Execute fused and unfused kernels at a point against the jnp oracle;
     returns the max relative error (conformance measures programs that
     compute the right thing, not just programs that move the right bytes)."""

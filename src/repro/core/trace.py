@@ -1338,7 +1338,7 @@ def _power_law_sharded_trace(*, n_nodes, n_edges, seed=0,
     unique-pair merges — the full edge list never materializes on one
     host, so the builder reaches 10⁸–10⁹ edges (DESIGN.md §14).  The
     shard count is an execution detail, *not* graph identity: it comes
-    from ``REPRO_TRACE_SHARDS`` (else the host's device/CPU count) and
+    from ``REPRO_TRACE_SHARDS`` (else the host's CPU count) and
     never enters the cache key.
     """
     from repro.distributed import trace_shard

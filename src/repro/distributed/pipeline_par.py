@@ -19,8 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from ..compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -66,7 +64,7 @@ def gpipe_apply(stage_fn: Callable, stage_params, x_micro: Array, *,
             jnp.where(r == n_stages - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -29,8 +29,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from ..compat import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -146,7 +144,7 @@ def allgather_spmm(h: Array, part_senders: Array, part_receivers: Array,
         msgs = h_full[snd[0]] * wgt[0][:, None]
         return jax.ops.segment_sum(msgs, rcv[0], num_segments=n_local)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ax, None), P(ax, None), P(ax, None), P(ax, None)),
         out_specs=P(ax, None),
@@ -194,7 +192,7 @@ def ring_spmm(h: Array, part_senders: Array, part_receivers: Array,
         _, acc = jax.lax.fori_loop(0, n_shards, hop, (h_loc, acc0))
         return acc
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(ax, None), P(ax, None, None), P(ax, None, None),
                   P(ax, None, None)),

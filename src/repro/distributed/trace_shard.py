@@ -80,10 +80,10 @@ _SPLITTER_OVERSAMPLE = 64
 def default_shard_count() -> int:
     """Shard count: ``REPRO_TRACE_SHARDS`` env, else the CPU count.
 
-    When jax is already loaded (e.g. under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) the local
-    device count wins over the CPU count, so the simulated-mesh CI job
-    exercises one shard per simulated device without extra plumbing.
+    Shards are NumPy threads, so this never asks jax for its devices: that
+    would initialize a backend (the TPU, on a chip host) from a host-only
+    path.  The simulated-mesh CI job pins one shard per simulated device
+    through ``REPRO_TRACE_SHARDS``.
     """
     raw = os.environ.get("REPRO_TRACE_SHARDS", "").strip()
     if raw:
@@ -97,13 +97,6 @@ def default_shard_count() -> int:
             raise ValueError(
                 f"REPRO_TRACE_SHARDS must be a positive integer, got {n}")
         return n
-    import sys
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        try:
-            return max(1, int(jax.local_device_count()))
-        except Exception:
-            pass
     return max(1, os.cpu_count() or 1)
 
 

@@ -24,13 +24,15 @@ schedule, not a transcription (DESIGN.md §10).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .edge_aggregate import DEFAULT_BLOCK_K, DEFAULT_BLOCK_N
+from . import resolve_interpret
+from .edge_aggregate import DEFAULT_BLOCK_K, DEFAULT_BLOCK_N, mxu_dot
 
 
 def _aggregate_kernel(a_ref, x_ref, out_ref, acc_ref, *, n_src_blocks: int):
@@ -40,8 +42,7 @@ def _aggregate_kernel(a_ref, x_ref, out_ref, acc_ref, *, n_src_blocks: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(a_ref[...], x_ref[...],
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += mxu_dot(a_ref[...], x_ref[...])
 
     @pl.when(j == n_src_blocks - 1)
     def _flush():
@@ -50,9 +51,7 @@ def _aggregate_kernel(a_ref, x_ref, out_ref, acc_ref, *, n_src_blocks: int):
 
 
 def _combine_kernel(y_ref, w_ref, out_ref):
-    out_ref[...] = jnp.dot(y_ref[...], w_ref[...],
-                           preferred_element_type=jnp.float32
-                           ).astype(out_ref.dtype)
+    out_ref[...] = mxu_dot(y_ref[...], w_ref[...]).astype(out_ref.dtype)
 
 
 def aggregate_grid_spec(n: int, f: int, block_n: int, block_k: int):
@@ -120,7 +119,7 @@ def combine_block_streams(n: int, f: int, t: int, *,
 def aggregate_pass(adjacency: jax.Array, x: jax.Array, *,
                    block_n: int = DEFAULT_BLOCK_N,
                    block_k: int = DEFAULT_BLOCK_K,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """Y_agg = A @ X with A (N, N) block-dense, X (N, F)."""
     n, f = x.shape
     assert adjacency.shape == (n, n), (adjacency.shape, n)
@@ -135,13 +134,13 @@ def aggregate_pass(adjacency: jax.Array, x: jax.Array, *,
         out_specs=pl.BlockSpec(*out_geom),
         out_shape=jax.ShapeDtypeStruct((n, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, f), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(adjacency, x)
 
 
 def combine_pass(y_agg: jax.Array, w: jax.Array, *,
                  block_n: int = DEFAULT_BLOCK_N,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """Y = Y_agg @ W with Y_agg (N, F), W (F, T)."""
     n, f = y_agg.shape
     t = w.shape[1]
@@ -155,7 +154,7 @@ def combine_pass(y_agg: jax.Array, w: jax.Array, *,
         in_specs=[pl.BlockSpec(shape, imap) for shape, imap in in_geoms],
         out_specs=pl.BlockSpec(*out_geom),
         out_shape=jax.ShapeDtypeStruct((n, t), y_agg.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(y_agg, w)
 
 
@@ -163,7 +162,7 @@ def unfused_aggregate_combine(adjacency: jax.Array, x: jax.Array,
                               w: jax.Array, *,
                               block_n: int = DEFAULT_BLOCK_N,
                               block_k: int = DEFAULT_BLOCK_K,
-                              interpret: bool = True) -> jax.Array:
+                              interpret: Optional[bool] = None) -> jax.Array:
     """Two-pass Y = (A @ X) @ W — numerically the fused kernel's oracle
     twin; the aggregate round-trips through memory between the passes."""
     y_agg = aggregate_pass(adjacency, x, block_n=block_n, block_k=block_k,

@@ -13,10 +13,14 @@ block (revisited across the bag dimension).
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import resolve_interpret
 
 
 def _kernel(idx_ref, table_ref, out_ref):
@@ -30,7 +34,7 @@ def _kernel(idx_ref, table_ref, out_ref):
 
 
 def embedding_bag(table: jax.Array, indices: jax.Array, *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """table (V, D) f32, indices (B, hot) int32 -> (B, D) summed bags."""
     v, d = table.shape
     b, hot = indices.shape
@@ -47,5 +51,5 @@ def embedding_bag(table: jax.Array, indices: jax.Array, *,
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, d), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(indices, table)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import resolve_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG = -1e30
@@ -75,7 +77,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          softcap: Optional[float] = None,
                          block_q: int = DEFAULT_BLOCK_Q,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q, k, v: (BH, S, D) -> (BH, S, D)."""
     bh, s, d = q.shape
     block_q = min(block_q, s)
@@ -101,5 +103,5 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

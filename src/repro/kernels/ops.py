@@ -1,7 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True (CPU validation per the brief); on real TPU
-hardware the launcher flips it to False.  ``flash_attention`` takes the
+``interpret=None`` (the default) is resolved by
+:func:`repro.kernels.resolve_interpret`: compiled for the chip on a TPU
+backend, Pallas interpret mode elsewhere.  ``flash_attention`` takes the
 model-layout (B, S, H, D) tensors and handles the (B*H, S, D) flattening +
 GQA head replication so :mod:`repro.models.attention` can swap it in
 one-for-one.
@@ -24,7 +25,7 @@ from .flash_attention import flash_attention_bhsd
 @partial(jax.jit, static_argnames=("block_n", "block_k", "interpret"))
 def gnn_aggregate_combine(adjacency: jax.Array, x: jax.Array, w: jax.Array,
                           *, block_n: int = 256, block_k: int = 256,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: Optional[bool] = None) -> jax.Array:
     return fused_aggregate_combine(adjacency, x, w, block_n=block_n,
                                    block_k=block_k, interpret=interpret)
 
@@ -32,7 +33,7 @@ def gnn_aggregate_combine(adjacency: jax.Array, x: jax.Array, w: jax.Array,
 @partial(jax.jit, static_argnames=("block_n", "block_k", "interpret"))
 def gnn_aggregate(adjacency: jax.Array, x: jax.Array, *,
                   block_n: int = 256, block_k: int = 256,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """Unfused pass 1: Y_agg = A @ X (the aggregate materializes in HBM)."""
     return aggregate_pass(adjacency, x, block_n=block_n, block_k=block_k,
                           interpret=interpret)
@@ -40,7 +41,7 @@ def gnn_aggregate(adjacency: jax.Array, x: jax.Array, *,
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
 def gnn_combine(y_agg: jax.Array, w: jax.Array, *, block_n: int = 256,
-                interpret: bool = True) -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """Unfused pass 2: Y = Y_agg @ W (reads the inter-phase buffer back).
 
     Jitted separately from :func:`gnn_aggregate` on purpose — the pair is
@@ -55,7 +56,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q (B, S, H, D); k, v (B, S, Hk, D) with Hk | H (GQA)."""
     b, s, h, d = q.shape
     hk = k.shape[2]
@@ -73,5 +74,5 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 @partial(jax.jit, static_argnames=("interpret",))
 def embedding_bag(table: jax.Array, indices: jax.Array, *,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     return _embedding_bag(table, indices, interpret=interpret)

@@ -8,9 +8,15 @@ import jax.numpy as jnp
 
 def fused_aggregate_combine_ref(adjacency: jax.Array, x: jax.Array,
                                 w: jax.Array) -> jax.Array:
-    """Y = (A @ X) @ W in fp32 accumulation."""
-    agg = jnp.dot(adjacency.astype(jnp.float32), x.astype(jnp.float32))
-    return jnp.dot(agg, w.astype(jnp.float32)).astype(x.dtype)
+    """Y = (A @ X) @ W in fp32 at full precision.
+
+    ``Precision.HIGHEST``: a TPU's default f32 dot is a reduced-precision
+    MXU pass, which would make the oracle, not the kernel, miss the
+    numerics bar."""
+    hi = jax.lax.Precision.HIGHEST
+    agg = jnp.dot(adjacency.astype(jnp.float32), x.astype(jnp.float32),
+                  precision=hi)
+    return jnp.dot(agg, w.astype(jnp.float32), precision=hi).astype(x.dtype)
 
 
 def edge_list_aggregate_ref(x: jax.Array, senders: jax.Array,

@@ -20,11 +20,14 @@ This module is the accelerator-resident version of that pass:
   sweep's max tile count).
 * :func:`tile_histogram` — the Pallas segment-reduce kernel: grid over
   edge blocks, each block one-hot-expands its tile ids against a
-  broadcasted iota and accumulates ``weights @ onehot`` on the MXU into
-  a VMEM-resident ``(1, n_tiles)`` output (the same masked-matmul trick
-  the block-dense SpMM kernels use — the MXU eats the zeros).  Runs
-  under ``interpret=True`` on CPU in CI; float32 accumulation is exact
-  for integer counts below 2^24 per tile (asserted by the wrapper).
+  broadcasted iota into a ``(n_tiles, block)`` matrix and contracts it
+  with the block's weights on the MXU into a VMEM-resident
+  ``(1, n_tiles)`` output (the same masked-matmul trick the block-dense
+  SpMM kernels use — the MXU eats the zeros).  Interpreted on the CPU,
+  compiled on a TPU (:func:`repro.kernels.resolve_interpret`); float32
+  accumulation at full contraction precision is exact for integer counts
+  below 2^24 per tile (asserted by the wrapper), and a one-hot block
+  larger than the chip's VMEM is refused.
 * :func:`schedule_counts_pallas` — the halo/multiplicity counts routed
   through the Pallas kernel, numpy-parity-pinned in the test battery.
 
@@ -36,16 +39,22 @@ the default and the semantic reference.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.tpu_model import TPU_V5E
+
+from . import resolve_interpret
+
 __all__ = [
     "schedule_counts",
     "schedule_counts_pallas",
     "tile_histogram",
+    "padded_histogram",
     "boundary_flags",
 ]
 
@@ -53,6 +62,8 @@ __all__ = [
 DEFAULT_BLOCK_EDGES = 4096
 #: float32 accumulation holds integers exactly below this.
 _F32_EXACT = 1 << 24
+#: Largest one-hot block (bytes) the kernel may build: the chip's VMEM.
+_ONEHOT_LIMIT_BYTES = TPU_V5E.vmem_bytes
 
 
 def boundary_flags(new_src: jax.Array, tile: jax.Array) -> jax.Array:
@@ -109,13 +120,18 @@ def _hist_kernel(ids_ref, w_ref, out_ref):
     ids = ids_ref[...]                       # (1, B) int32 tile ids
     w = w_ref[...]                           # (1, B) float32 weights
     block, n = ids.shape[1], out_ref.shape[1]
-    # One-hot expansion against a broadcasted iota: row e selects the
-    # destination-tile column of edge e (padding ids select nothing).
-    onehot = (ids[0, :, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (block, n), 1)
+    # One-hot expansion against a broadcasted iota, tiles on the rows:
+    # column e selects the destination-tile row of edge e (padding ids
+    # select nothing).  Comparing against the (1, B) ids broadcasts them
+    # down the rows, so no gather is lowered.
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (n, block), 0) == ids
               ).astype(jnp.float32)
-    # (1, B) @ (B, n): the whole block's histogram in one MXU pass.
-    out_ref[...] += jnp.dot(w, onehot, preferred_element_type=jnp.float32)
+    # (1, B) x (n, B) contracted over B: the block's histogram in one MXU
+    # pass, at full f32 precision so integer counts stay exact.
+    out_ref[...] += jax.lax.dot_general(
+        w, onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -124,14 +140,16 @@ def _round_up(x: int, m: int) -> int:
 
 def tile_histogram(ids, weights, n_tiles: int, *,
                    block_edges: int = DEFAULT_BLOCK_EDGES,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """``bincount(ids, weights, minlength=n_tiles)`` as a Pallas kernel.
 
     ``ids`` int tile ids in ``[0, n_tiles)``, ``weights`` non-negative
     integer-valued counts (float32-able); both 1-D of equal length.
     Accumulates in float32 — exact for integer totals below 2^24, so the
     guard bounds the *accumulated weight* (total count), which also
-    bounds every per-tile total and every individual weight.
+    bounds every per-tile total and every individual weight.  Raises
+    ``ValueError`` where the ``(n_tiles, block_edges)`` one-hot block
+    would exceed the chip's VMEM; there is no fallback.
     """
     ids = jnp.asarray(ids, dtype=jnp.int32)
     weights = jnp.asarray(weights, dtype=jnp.float32)
@@ -150,26 +168,45 @@ def tile_histogram(ids, weights, n_tiles: int, *,
     block = int(block_edges)
     e_pad = _round_up(max(int(ids.shape[0]), 1), block)
     n_pad = _round_up(max(n_tiles, 1), 128)
-    # Pad ids with n_pad (matches no iota column) and weights with 0.
+    onehot_bytes = n_pad * block * 4
+    if onehot_bytes > _ONEHOT_LIMIT_BYTES:
+        raise ValueError(
+            f"tile_histogram builds a ({n_pad} x {block}) float32 one-hot "
+            f"block per grid step ({onehot_bytes / 2**20:.0f} MiB), more "
+            f"than the chip's {_ONEHOT_LIMIT_BYTES / 2**20:.0f} MiB of VMEM; "
+            "use fewer tiles (a larger capacity), smaller block_edges, or "
+            "the jitted segment_sum path (schedule_counts)")
+    # Pad ids with n_pad (matches no iota row) and weights with 0.
     ids2 = jnp.full((1, e_pad), n_pad, dtype=jnp.int32)
     ids2 = ids2.at[0, :ids.shape[0]].set(ids)
     w2 = jnp.zeros((1, e_pad), dtype=jnp.float32)
     w2 = w2.at[0, :weights.shape[0]].set(weights)
-    out = pl.pallas_call(
+    out = padded_histogram(ids2, w2, n_pad=n_pad, block_edges=block,
+                           interpret=interpret)
+    return out[0, :n_tiles]
+
+
+def padded_histogram(ids2, w2, *, n_pad: int, block_edges: int,
+                     interpret: Optional[bool] = None) -> jax.Array:
+    """The bare ``pallas_call`` of :func:`tile_histogram`: ``(1, E_pad)``
+    int32 ids and float32 weights, ``E_pad`` a multiple of
+    ``block_edges``, into a ``(1, n_pad)`` float32 histogram.  No guards;
+    shapes only, so it lowers from abstract operands."""
+    e_pad = ids2.shape[1]
+    return pl.pallas_call(
         _hist_kernel,
-        grid=(e_pad // block,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (0, i)),
-                  pl.BlockSpec((1, block), lambda i: (0, i))],
+        grid=(e_pad // block_edges,),
+        in_specs=[pl.BlockSpec((1, block_edges), lambda i: (0, i)),
+                  pl.BlockSpec((1, block_edges), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, n_pad), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(ids2, w2)
-    return out[0, :n_tiles]
 
 
 def schedule_counts_pallas(u_snd, u_rcv, u_new_src, mult, K, n_tiles: int, *,
                            block_edges: int = DEFAULT_BLOCK_EDGES,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """(halo_counts, remote_edge_counts) with the histograms on the
     Pallas kernel (float32; numpy-parity-pinned on CI sizes)."""
     u_snd = jnp.asarray(u_snd)

@@ -14,24 +14,13 @@ The ``model`` axis carries TP/EP/SP; ``data`` (x ``pod``) carries DP.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pinned jax 0.4.x: meshes are implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_production_mesh", "make_test_mesh"]
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

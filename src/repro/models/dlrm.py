@@ -21,8 +21,6 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-
-from ..compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .common import embed_init, mlp_apply, mlp_init
@@ -198,7 +196,7 @@ def vocab_parallel_embeddings(cfg: DLRMConfig, tables: Sequence[Array],
                 outs[t] = red[:, j]
         return jnp.stack(outs, axis=1)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=policy.mesh,
         in_specs=(specs, P(None, None, None)),   # batch replicated for lookup
         out_specs=P(None, None, None),

@@ -32,8 +32,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
-
 Array = jax.Array
 
 
@@ -131,7 +129,7 @@ def moe_ffn_ep(params: dict, x: Array, cfg: MoEConfig, *, axis_name: str):
     (E_loc, d, f).  Two all_to_all ops move capacity buckets to/from expert
     owners.
     """
-    ep = axis_size(axis_name)
+    ep = jax.lax.axis_size(axis_name)
     E, E_loc = cfg.n_experts, cfg.n_experts // ep
     T, d = x.shape
     C = _capacity(T, cfg)  # per-expert capacity contributed by this sender

@@ -30,8 +30,6 @@ from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-
-from ..compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import attention as attn_lib
@@ -261,7 +259,7 @@ def _context_parallel_attention(cfg, policy, q, k, v, window):
             q_chunk=min(cfg.q_chunk, s_loc), q_offset=r * s_loc)
 
     spec_q = P(dp, tp_axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_q, spec_q, spec_q),
         out_specs=spec_q,
@@ -314,7 +312,7 @@ def _moe_ep_sharded(cfg, policy, moe_params, h):
         "w_up": P(tp_axis, None, None),
         "w_down": P(tp_axis, None, None),
     }
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=policy.mesh,
         in_specs=(pspecs, P(dp, tp_axis, None)),
         out_specs=(P(dp, tp_axis, None), P()),
@@ -655,7 +653,7 @@ def _decode_attention_sharded(cfg, policy, decode, q, k_cache, v_cache,
             wv, m, z, seq_axes if len(seq_axes) > 1 else seq_axes[0])
         return out.astype(qs.dtype), kc, vc
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, q_spec, q_spec, P()),
         out_specs=(q_spec, cache_spec, cache_spec),

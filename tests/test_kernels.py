@@ -1,4 +1,5 @@
-"""Per-kernel allclose sweeps against the pure-jnp oracles (interpret mode),
+"""Per-kernel allclose sweeps against the pure-jnp oracles (interpret mode
+on the CPU),
 plus hypothesis property tests on the kernels' invariants."""
 
 import jax
@@ -6,8 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-pytest.importorskip(
-    "hypothesis", reason="property tests need hypothesis; pip install hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref

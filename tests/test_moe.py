@@ -5,8 +5,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-pytest.importorskip(
-    "hypothesis", reason="property tests need hypothesis; pip install hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.models.moe import (MoEConfig, init_moe_params, moe_ffn_capacity,

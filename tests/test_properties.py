@@ -11,64 +11,13 @@ Three families of invariants (ISSUE 2 satellite):
 * ``TiledGraphModel`` with tile capacity >= V degenerates to one tile with
   zero halo-reload bits.
 
-Runs under hypothesis when installed; otherwise a deterministic shim draws
-seeded samples from the same strategy ranges so the battery still executes
-(the repo's other property modules importorskip hypothesis — these
-invariants are pure float64 algebra and too cheap to skip).
+Runs under hypothesis, a dependency of the test suite
+(``requirements.txt``).
 """
 
 import numpy as np
 import pytest
-
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:  # deterministic fallback: same shapes, seeded draws
-    HAVE_HYPOTHESIS = False
-
-    class _IntStrategy:
-        def __init__(self, lo, hi):
-            self.lo, self.hi = lo, hi
-
-        def draw(self, rng):
-            return int(rng.integers(self.lo, self.hi + 1))
-
-    class _SampledStrategy:
-        def __init__(self, elems):
-            self.elems = list(elems)
-
-        def draw(self, rng):
-            return self.elems[int(rng.integers(len(self.elems)))]
-
-    class st:  # noqa: N801 - mirrors the hypothesis namespace
-        integers = staticmethod(lambda lo, hi: _IntStrategy(lo, hi))
-        sampled_from = staticmethod(_SampledStrategy)
-
-    def settings(**_kw):
-        return lambda fn: fn
-
-    def given(*strategies, n_examples=12):
-        """Like hypothesis.given: strategies fill the test's trailing
-        parameters (by name, so pytest.parametrize kwargs compose)."""
-        def deco(fn):
-            import functools
-            import inspect
-
-            sig_params = list(inspect.signature(fn).parameters.values())
-            drawn = [p.name for p in sig_params[len(sig_params) - len(strategies):]]
-
-            @functools.wraps(fn)
-            def wrapper(**kwargs):
-                rng = np.random.default_rng(0)
-                for _ in range(n_examples):
-                    fn(**kwargs,
-                       **{nm: s.draw(rng) for nm, s in zip(drawn, strategies)})
-
-            # hide the drawn parameters from pytest's fixture resolution
-            wrapper.__signature__ = inspect.Signature(
-                [p for p in sig_params if p.name not in drawn])
-            return wrapper
-        return deco
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (FullGraphParams, MultiLayerModel, TiledGraphModel,
                         paper_default_graph, registry)

@@ -12,8 +12,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-pytest.importorskip(
-    "hypothesis", reason="property tests need hypothesis; pip install hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.manager import CheckpointManager

@@ -143,6 +143,10 @@ def test_pallas_tile_histogram_matches_bincount():
     with pytest.raises(ValueError, match="float32"):
         sr.tile_histogram(np.zeros(2, np.int32),
                           np.full(2, 2.0**24, np.float32), 4)
+    # a one-hot block over the chip's VMEM is refused, not run elsewhere:
+    # 8192 tiles x 4096 edges x 4 B is exactly 128 MiB, one more is over
+    with pytest.raises(ValueError, match="VMEM"):
+        sr.tile_histogram(ids, w, 8192 + 1)
 
 
 def test_big_power_law_reference_parity_and_bruteforce():

@@ -228,6 +228,32 @@ def test_default_shard_count_env(monkeypatch):
     assert trace_shard.default_shard_count() >= 1
 
 
+def test_default_shard_count_is_cpu_count_and_leaves_jax_alone():
+    """Unset ``REPRO_TRACE_SHARDS``: the CPU count, even with jax imported
+    and several host devices on offer, and no backend is initialized (on
+    a chip host that would claim the TPU from a host-only path)."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import os, jax\n"
+        "from jax._src import xla_bridge\n"
+        "from repro.distributed import trace_shard\n"
+        "n = trace_shard.default_shard_count()\n"
+        "print(n, os.cpu_count(), xla_bridge.backends_are_initialized())\n")
+    env = {k: v for k, v in os.environ.items() if k != "REPRO_TRACE_SHARDS"}
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=3"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True).stdout.split()
+    assert out == [str(max(1, os.cpu_count() or 1)), str(os.cpu_count()),
+                   "False"]
+
+
 def test_oversized_vertex_space_refused():
     with pytest.raises(NotImplementedError, match="int64"):
         trace_shard.sharded_power_law_factorization(
