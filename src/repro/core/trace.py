@@ -51,7 +51,9 @@ from __future__ import annotations
 
 import functools
 import json
+import sys
 import threading
+import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping, Optional, Sequence
@@ -84,13 +86,21 @@ _ENGINES = ("numpy", "jax", "sharded")
 #: edge-list sorts, schedule computations, schedule-cache hits, and
 #: builder invocations actually happened.  The §15 tuner's cache-reuse
 #: regression gates on ``factorizations`` — a multi-capacity tune must
-#: never silently re-sort the edge list per candidate.
+#: never silently re-sort the edge list per candidate.  Beside them, the
+#: span totals of the schedule engine (:class:`_span`): host nanoseconds
+#: summed over calls (over threads too, so they can exceed wall time),
+#: and the bytes the device engine uploads.  All values are integers.
 _TRACE_STATS = {
     "factorizations": 0,     # actual sorts (not disk-cache rehydrations)
     "schedule_computes": 0,  # per-capacity O(U) boundary-flag passes
     "schedule_cache_hits": 0,  # per-trace LRU hits
     "schedule_disk_hits": 0,   # on-disk schedule_cache hits
     "trace_builds": 0,       # dataset builder invocations (cold resolves)
+    "schedules_ns": 0,       # GraphTrace.schedule(s), any engine, lock wait
+    "schedule_upload_ns": 0,     # jax engine: operands to the device
+    "schedule_upload_bytes": 0,  # jax engine: nbytes of those operands
+    "schedule_dispatch_ns": 0,   # jax engine: enqueueing the program
+    "schedule_wait_ns": 0,       # jax engine: device compute + read-back
 }
 
 #: Guards ``_TRACE_STATS`` read-modify-write cycles.  The serve engine
@@ -109,6 +119,35 @@ _CACHE_LOCK = threading.RLock()
 def _bump_stat(name: str, n: int = 1) -> None:
     with _STATS_LOCK:
         _TRACE_STATS[name] += n
+
+
+class _span:
+    """Context manager: add the block's host nanoseconds to the integer
+    ``_TRACE_STATS[counter]`` and mark it as ``name`` on the profiler's
+    host timeline (a ``jax.profiler.TraceAnnotation``, which records only
+    while a profiler session runs), so device gaps line up with it.
+
+    jax is only looked up, never imported: a process that has not
+    imported it runs no profiler session, and the numpy engine path
+    stays free of it."""
+
+    __slots__ = ("_counter", "_annotation", "_t0")
+
+    def __init__(self, name: str, counter: str) -> None:
+        self._counter = counter
+        jax = sys.modules.get("jax")
+        self._annotation = (None if jax is None
+                            else jax.profiler.TraceAnnotation(name))
+
+    def __enter__(self) -> None:
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc) -> None:
+        _bump_stat(self._counter, time.perf_counter_ns() - self._t0)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
 
 
 def reset_trace_stats() -> None:
@@ -749,24 +788,26 @@ class GraphTrace:
         segmented counts through the jitted path in
         :mod:`repro.kernels.segment_reduce` (bit-identical integers).
         """
-        cap = self._validate_cap(tile_vertices)
-        if engine not in _ENGINES:
-            raise ValueError(f"unknown trace engine {engine!r}; "
-                             f"expected one of {_ENGINES}")
-        # Held across the compute so concurrent callers of the same
-        # capacity see exactly one schedule_computes bump (the §18 serve
-        # metrics count on it) instead of racing duplicate passes.
-        with self._lock:
-            sched = self._cached_schedule(cap)
-            if sched is None:
-                if engine == "jax":
-                    sched = self._compute_schedules_jax([cap])[0]
-                elif engine == "sharded":
-                    sched = self._compute_schedules_sharded([cap])[0]
-                else:
-                    sched = self._compute_schedule(cap)
-                self._remember_schedule(cap, sched)
-            return sched
+        with _span("repro.trace.schedules", "schedules_ns"):
+            cap = self._validate_cap(tile_vertices)
+            if engine not in _ENGINES:
+                raise ValueError(f"unknown trace engine {engine!r}; "
+                                 f"expected one of {_ENGINES}")
+            # Held across the compute so concurrent callers of the same
+            # capacity see exactly one schedule_computes bump (the §18
+            # serve metrics count on it) instead of racing duplicate
+            # passes.
+            with self._lock:
+                sched = self._cached_schedule(cap)
+                if sched is None:
+                    if engine == "jax":
+                        sched = self._compute_schedules_jax([cap])[0]
+                    elif engine == "sharded":
+                        sched = self._compute_schedules_sharded([cap])[0]
+                    else:
+                        sched = self._compute_schedule(cap)
+                    self._remember_schedule(cap, sched)
+                return sched
 
     def schedules(self, tile_vertices: Sequence, *,
                   engine: str = "numpy") -> tuple[TraceSchedule, ...]:
@@ -777,33 +818,35 @@ class GraphTrace:
         capacity; results come back in input order (duplicates allowed)
         and land in the same per-trace LRU that :meth:`schedule` uses.
         """
-        caps = [self._validate_cap(c) for c in tile_vertices]
-        if engine not in _ENGINES:
-            raise ValueError(f"unknown trace engine {engine!r}; "
-                             f"expected one of {_ENGINES}")
-        # Results are held locally so a sweep wider than the schedule LRU
-        # still returns every schedule (the LRU may evict early entries
-        # while later capacities compute).
-        found: dict[int, TraceSchedule] = {}
-        missing = []
-        with self._lock:
-            for cap in dict.fromkeys(caps):
-                sched = self._cached_schedule(cap)
-                if sched is None:
-                    missing.append(cap)
-                else:
-                    found[cap] = sched
-            if missing:
-                if engine == "jax":
-                    computed = self._compute_schedules_jax(missing)
-                elif engine == "sharded":
-                    computed = self._compute_schedules_sharded(missing)
-                else:
-                    computed = [self._compute_schedule(c) for c in missing]
-                for cap, sched in zip(missing, computed):
-                    self._remember_schedule(cap, sched)
-                    found[cap] = sched
-        return tuple(found[c] for c in caps)
+        with _span("repro.trace.schedules", "schedules_ns"):
+            caps = [self._validate_cap(c) for c in tile_vertices]
+            if engine not in _ENGINES:
+                raise ValueError(f"unknown trace engine {engine!r}; "
+                                 f"expected one of {_ENGINES}")
+            # Results are held locally so a sweep wider than the schedule
+            # LRU still returns every schedule (the LRU may evict early
+            # entries while later capacities compute).
+            found: dict[int, TraceSchedule] = {}
+            missing = []
+            with self._lock:
+                for cap in dict.fromkeys(caps):
+                    sched = self._cached_schedule(cap)
+                    if sched is None:
+                        missing.append(cap)
+                    else:
+                        found[cap] = sched
+                if missing:
+                    if engine == "jax":
+                        computed = self._compute_schedules_jax(missing)
+                    elif engine == "sharded":
+                        computed = self._compute_schedules_sharded(missing)
+                    else:
+                        computed = [self._compute_schedule(c)
+                                    for c in missing]
+                    for cap, sched in zip(missing, computed):
+                        self._remember_schedule(cap, sched)
+                        found[cap] = sched
+            return tuple(found[c] for c in caps)
 
     def _compute_schedules_jax(self, caps: Sequence[int]) -> list[TraceSchedule]:
         """The jitted engine: one compile per sweep (padded tile axis)."""
@@ -818,15 +861,17 @@ class GraphTrace:
             _bump_stat("schedule_computes")
             halo, remote = segment_reduce.schedule_counts(
                 u_snd, u_rcv, u_new_src, mult, K, n_pad)
+            # Host work while the device computes; the read-backs wait.
             boundaries = self._tile_boundaries(n_tiles, K)
+            vertex_counts = np.diff(boundaries).astype(np.float64)
+            edge_counts = np.diff(self.row_ptr[boundaries]).astype(np.float64)
+            with _span("repro.trace.schedule.wait", "schedule_wait_ns"):
+                halo, remote = np.asarray(halo), np.asarray(remote)
             out.append(TraceSchedule(
                 n_tiles=int(n_tiles), capacity=int(cap), K=int(K),
-                vertex_counts=np.diff(boundaries).astype(np.float64),
-                edge_counts=np.diff(
-                    self.row_ptr[boundaries]).astype(np.float64),
-                halo_counts=np.asarray(halo)[:n_tiles].astype(np.float64),
-                remote_edge_counts=np.asarray(
-                    remote)[:n_tiles].astype(np.float64),
+                vertex_counts=vertex_counts, edge_counts=edge_counts,
+                halo_counts=halo[:n_tiles].astype(np.float64),
+                remote_edge_counts=remote[:n_tiles].astype(np.float64),
                 _pair_source=functools.partial(self._pairs_for, K)))
         return out
 
@@ -1206,7 +1251,10 @@ def trace_cache_info() -> dict:
     """Entries / bytes / budget of the in-process resolved-trace LRU,
     plus the process-wide work counters (``stats``: factorizations,
     schedule computes/hits, builder invocations — see
-    :func:`reset_trace_stats`)."""
+    :func:`reset_trace_stats`) and, beside them, the schedule engine's
+    span totals: ``schedules_ns`` (whole ``schedule``/``schedules``
+    calls), ``schedule_upload_ns`` and ``schedule_upload_bytes``,
+    ``schedule_dispatch_ns`` and ``schedule_wait_ns`` (device engine)."""
     with _CACHE_LOCK:
         entries = len(_TRACE_CACHE)
         nbytes = int(sum(t.nbytes for t in _TRACE_CACHE.values()))

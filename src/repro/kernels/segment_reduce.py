@@ -17,7 +17,9 @@ This module is the accelerator-resident version of that pass:
   the numpy engine, pinned in tests).  The tile axis is padded to a
   static ``n_tiles_pad`` so a whole capacity sweep shares ONE
   compilation (``GraphTrace.schedules(caps, engine="jax")`` passes the
-  sweep's max tile count).
+  sweep's max tile count).  Its operand upload and its dispatch are
+  timed as the ``repro.trace.schedule.upload`` / ``.dispatch`` spans,
+  and the bytes uploaded counted, in :mod:`repro.core.trace`'s counters.
 * :func:`tile_histogram` — the Pallas segment-reduce kernel: grid over
   edge blocks, each block one-hot-expands its tile ids against a
   broadcasted iota into a ``(n_tiles, block)`` matrix and contracts it
@@ -47,6 +49,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.tpu_model import TPU_V5E
+from repro.core.trace import _bump_stat, _span
 
 from . import resolve_interpret
 
@@ -99,12 +102,13 @@ def schedule_counts(u_snd, u_rcv, u_new_src, mult, K, n_tiles_pad: int):
     to its max tile count shares one compilation.  Integer-exact (int32
     segment sums; counts are bounded by E).
     """
-    u_snd = jnp.asarray(u_snd)
-    u_rcv = jnp.asarray(u_rcv)
-    return _schedule_counts_jnp(u_snd, u_rcv, jnp.asarray(u_new_src),
-                                jnp.asarray(mult, jnp.int32),
-                                jnp.asarray(K, u_rcv.dtype),
-                                int(n_tiles_pad))
+    with _span("repro.trace.schedule.upload", "schedule_upload_ns"):
+        u_rcv = jnp.asarray(u_rcv)
+        operands = (jnp.asarray(u_snd), u_rcv, jnp.asarray(u_new_src),
+                    jnp.asarray(mult, jnp.int32), jnp.asarray(K, u_rcv.dtype))
+    _bump_stat("schedule_upload_bytes", sum(a.nbytes for a in operands))
+    with _span("repro.trace.schedule.dispatch", "schedule_dispatch_ns"):
+        return _schedule_counts_jnp(*operands, int(n_tiles_pad))
 
 
 # ---------------------------------------------------------------------------

@@ -631,3 +631,69 @@ def test_schedule_counts_exact_at_2p53_boundary(total):
         assert [int(x) for x in sched.remote_edge_counts] == remote
         assert sched.cut_edges == sum(remote)
     assert sched.halo_total == sum(halo)
+
+
+# ---------------------------------------------------------------------------
+# Schedule-engine spans and counters: host time per step and bytes uploaded,
+# in trace_cache_info()["stats"] and on the profiler's host timeline.
+# ---------------------------------------------------------------------------
+
+_DEVICE_SPAN_COUNTERS = ("schedule_upload_ns", "schedule_dispatch_ns",
+                         "schedule_wait_ns")
+
+
+def _simple_trace(V=96, seed=3):
+    """A graph with one edge per (sender, receiver) pair, so U = E."""
+    u_snd, u_rcv = _dense_pairs(V, seed)
+    trace = GraphTrace(u_snd, u_rcv, V)
+    assert trace.n_edges == u_snd.size
+    return trace
+
+
+@pytest.mark.parametrize("engine", ["jax", "numpy"])
+def test_schedule_spans_and_upload_counters(engine):
+    trace = _simple_trace()
+    U, caps = trace.n_edges, [8, 16, 32]
+    trace._pair_factorization()           # the sort is not a span's work
+    before = dict(trace_cache_info()["stats"])
+    trace.schedules(caps, engine=engine)
+    after = trace_cache_info()["stats"]
+    d = {k: after[k] - before[k] for k in after}
+    on_device = engine == "jax"
+    # Per capacity: int32 senders, receivers and multiplicities and the
+    # bool new-sender mask (13 B per pair), plus the int32 stride.
+    assert d["schedule_upload_bytes"] == on_device * len(caps) * (13 * U + 4)
+    for key in _DEVICE_SPAN_COUNTERS:
+        assert (d[key] > 0) == on_device, key
+    assert d["schedules_ns"] >= sum(d[k] for k in _DEVICE_SPAN_COUNTERS)
+    assert d["schedules_ns"] > 0
+
+
+def test_schedule_spans_nest_on_the_profiler_timeline(tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    trace = _simple_trace()
+    trace.schedules([8], engine="jax")    # compile outside the profile
+    trace.clear_schedules()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        trace.schedules([8, 16], engine="jax")
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.trace.")]
+    outer = [s for s in spans if s[0] == "repro.trace.schedules"]
+    inner = [s for s in spans if s[0].startswith("repro.trace.schedule.")]
+    assert len(outer) == 1
+    assert sorted(s[0] for s in inner) == sorted(
+        2 * ["repro.trace.schedule.upload", "repro.trace.schedule.dispatch",
+             "repro.trace.schedule.wait"])
+    _, lo, hi = outer[0]
+    assert all(lo <= start and end <= hi for _, start, end in inner)

@@ -455,7 +455,9 @@ def test_reset_trace_stats_zeroes_all_counters():
     stats = trace_cache_info()["stats"]
     assert set(stats) == {"factorizations", "schedule_computes",
                           "schedule_cache_hits", "schedule_disk_hits",
-                          "trace_builds"}
+                          "trace_builds", "schedules_ns",
+                          "schedule_upload_ns", "schedule_upload_bytes",
+                          "schedule_dispatch_ns", "schedule_wait_ns"}
     assert all(v == 0 for v in stats.values())
 
 
