@@ -101,6 +101,8 @@ _TRACE_STATS = {
     "schedule_upload_bytes": 0,  # jax engine: nbytes of those operands
     "schedule_dispatch_ns": 0,   # jax engine: enqueueing the program
     "schedule_wait_ns": 0,       # jax engine: device compute + read-back
+    "schedule_hist_mxu": 0,      # jax engine: capacities on the MXU kernel
+    "schedule_hist_scatter": 0,  # jax engine: capacities on scatter-adds
 }
 
 #: Guards ``_TRACE_STATS`` read-modify-write cycles.  The serve engine
@@ -1254,7 +1256,9 @@ def trace_cache_info() -> dict:
     :func:`reset_trace_stats`) and, beside them, the schedule engine's
     span totals: ``schedules_ns`` (whole ``schedule``/``schedules``
     calls), ``schedule_upload_ns`` and ``schedule_upload_bytes``,
-    ``schedule_dispatch_ns`` and ``schedule_wait_ns`` (device engine)."""
+    ``schedule_dispatch_ns`` and ``schedule_wait_ns``, and the histogram
+    path per capacity, ``schedule_hist_mxu`` / ``schedule_hist_scatter``
+    (device engine)."""
     with _CACHE_LOCK:
         entries = len(_TRACE_CACHE)
         nbytes = int(sum(t.nbytes for t in _TRACE_CACHE.values()))

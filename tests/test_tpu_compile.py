@@ -4,9 +4,9 @@ The TPU compiler is installed with jax and compiles for a topology that is
 described, not attached: what it refuses here it would refuse on the chip.
 Every case passes ``interpret=False`` explicitly, at the widths the system
 runs: GCN-Cora (``configs/gcn_cora``) on a Cora-sized graph padded to 2816
-nodes, the device schedule engine at 10^7 unique pairs, and the Pallas
-histogram.  Nothing runs; results are checked on the chip by
-``chip_smoke.py``.
+nodes, the device schedule engine at 10^7 unique pairs and at
+ogbn-products' 123.7 M, and the Pallas histogram.  Nothing runs; results
+are checked on the chip by ``chip_smoke.py``.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library, and under pytest-xdist every
@@ -95,16 +95,31 @@ def test_unfused_pair_compiles_at_gcn_cora_layer1(one_chip):
         assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_device_schedule_engine_compiles_at_1e7_pairs(one_chip):
-    u = 10_000_000
-    n_tiles_pad = 62_500   # a 10^6-node trace at capacity 16
+def _compile_schedule_engine(sharding, u, n_tiles_pad):
     compiled = sr._schedule_counts_jnp.lower(
-        _spec(one_chip, (u,), jnp.int32), _spec(one_chip, (u,), jnp.int32),
-        _spec(one_chip, (u,), jnp.bool_), _spec(one_chip, (u,), jnp.int32),
-        _spec(one_chip, (), jnp.int32), n_tiles_pad).compile()
+        _spec(sharding, (u,), jnp.int32), _spec(sharding, (u,), jnp.int32),
+        _spec(sharding, (u,), jnp.bool_), _spec(sharding, (u,), jnp.int32),
+        _spec(sharding, (), jnp.int32), n_tiles_pad,
+        interpret=False).compile()
     halo, cut = compiled.out_info
     assert halo.shape == cut.shape == (n_tiles_pad,)
     assert halo.dtype == cut.dtype == jnp.int32
+    return compiled.as_text()
+
+
+def test_device_schedule_engine_compiles_at_1e7_pairs(one_chip):
+    n_tiles_pad = 62_500   # a 10^6-node trace at capacity 16
+    hlo = _compile_schedule_engine(one_chip, 10_000_000, n_tiles_pad)
+    # The MXU histogram kernel at or below the crossover, scatters above.
+    assert ("tpu_custom_call" in hlo) == (
+        n_tiles_pad <= sr.MXU_HIST_MAX_TILES)
+
+
+def test_products_sweep_takes_the_mxu_histogram(one_chip):
+    """ogbn-products' capacity sweep: U = 123,718,280 unique pairs and
+    2,392 tiles at capacity 2^10 compile to the MXU histogram kernel."""
+    hlo = _compile_schedule_engine(one_chip, 123_718_280, 2392)
+    assert "tpu_custom_call" in hlo
 
 
 @pytest.mark.parametrize("n_pad", [128, 1024])

@@ -149,6 +149,66 @@ def test_pallas_tile_histogram_matches_bincount():
         sr.tile_histogram(ids, w, 8192 + 1)
 
 
+def _mxu_and_scatter(ids, halo, cut, n_tiles_pad):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import segment_reduce as sr
+
+    ids, halo, cut = (jnp.asarray(a, jnp.int32) for a in (ids, halo, cut))
+    mxu = jax.jit(lambda t, h, c: sr._mxu_histograms(
+        t, h, c, jnp.max(c), n_tiles_pad))
+    scatter = jax.jit(lambda t, h, c: sr._scatter_histograms(
+        t, h, c, n_tiles_pad))
+    return ([np.asarray(x) for x in mxu(ids, halo, cut)],
+            [np.asarray(x) for x in scatter(ids, halo, cut)])
+
+
+@pytest.mark.parametrize("weights", [(1,), (1, 255, 256, 65_537)],
+                         ids=["simple", "multigraph"])
+@pytest.mark.parametrize("n_tiles_pad", [1, 127, 128, 129, 677, 2392])
+def test_mxu_histograms_bitmatch_bincount_and_scatter(n_tiles_pad, weights):
+    """The two-level one-hot histogram (interpret mode here) is
+    bit-identical to np.bincount and to the scatter-adds, on the one-plane
+    branch (weights that fit a byte) and on the four-plane branch."""
+    rng = np.random.default_rng(n_tiles_pad)
+    u = 3000
+    ids = rng.integers(0, n_tiles_pad, size=u)
+    halo = rng.integers(0, 2, size=u)
+    cut = np.where(rng.random(u) < 0.7, rng.choice(weights, size=u), 0)
+    mxu, scatter = _mxu_and_scatter(ids, halo, cut, n_tiles_pad)
+    want = (np.bincount(ids, halo, n_tiles_pad),
+            np.bincount(ids, cut.astype(np.float64), n_tiles_pad))
+    for got, ref, exact in zip(mxu, scatter, want):
+        assert got.dtype == np.int32 and got.shape == (n_tiles_pad,)
+        np.testing.assert_array_equal(got, exact)
+        np.testing.assert_array_equal(got, ref)
+
+
+def test_mxu_histogram_exact_past_2p24_across_blocks():
+    """One tile's cut total passes 2^24 over several edge blocks: each
+    block's float32 partial stays exact and the blocks add in int32."""
+    from repro.kernels import segment_reduce as sr
+
+    block = sr._MXU_BLOCK_EDGES
+    n_tiles_pad, u = 300, 4 * block
+    ids = np.arange(u) % n_tiles_pad
+    ids[::256] = 7                        # tile 7 gets edges in every block
+    halo = np.ones(u, np.int64)
+    cut = np.where(ids == 7, 65_537, 1)
+    total = int(cut[ids == 7].sum())
+    per_block = [int(cut[b:b + block][ids[b:b + block] == 7].sum())
+                 for b in range(0, u, block)]
+    assert total > 2**24 and max(per_block) < 2**24
+    mxu, scatter = _mxu_and_scatter(ids, halo, cut, n_tiles_pad)
+    assert int(mxu[1][7]) == total
+    for got, ref in zip(mxu, scatter):
+        np.testing.assert_array_equal(got, ref)
+    np.testing.assert_array_equal(mxu[0], np.bincount(ids, minlength=300))
+    np.testing.assert_array_equal(
+        mxu[1], np.bincount(ids, cut.astype(np.float64), 300))
+
+
 def test_big_power_law_reference_parity_and_bruteforce():
     """The >= 100k-edge acceptance point, rerun through the new engine."""
     params = {"n_nodes": 20000.0, "n_edges": 120000.0, "seed": 0.0,
@@ -667,6 +727,36 @@ def test_schedule_spans_and_upload_counters(engine):
         assert (d[key] > 0) == on_device, key
     assert d["schedules_ns"] >= sum(d[k] for k in _DEVICE_SPAN_COUNTERS)
     assert d["schedules_ns"] > 0
+    # 96 vertices: every capacity's tile count is on the MXU side.
+    assert d["schedule_hist_mxu"] == on_device * len(caps)
+    assert d["schedule_hist_scatter"] == 0
+
+
+@pytest.mark.parametrize("above", [False, True], ids=["at_T", "above_T"])
+def test_schedule_hist_counters_follow_the_crossover(above):
+    """One bump per capacity, on the side of the crossover T that the
+    static padded tile count lies on; the result is exact either way."""
+    from repro.kernels import segment_reduce as sr
+
+    n_tiles_pad = sr.MXU_HIST_MAX_TILES + above
+    u_snd = np.array([0, 0, 5, 9], np.int32)
+    u_rcv = np.array([3, 70, 1, 9], np.int32)
+    new_src = np.array([True, False, True, True])
+    mult = np.array([2, 1, 300, 1], np.int32)
+    before = dict(trace_cache_info()["stats"])
+    halo, cut = sr.schedule_counts(u_snd, u_rcv, new_src, mult, 4,
+                                   n_tiles_pad)
+    after = trace_cache_info()["stats"]
+    assert after["schedule_hist_mxu"] - before["schedule_hist_mxu"] == (
+        not above)
+    assert after["schedule_hist_scatter"] - before["schedule_hist_scatter"] \
+        == above
+    want_halo = np.zeros(n_tiles_pad, np.int64)
+    want_cut = np.zeros(n_tiles_pad, np.int64)
+    want_halo[[0, 17]] = 1                # pairs (0, 3) and (5, 1) are remote
+    want_cut[[0, 17]] = [300, 1]
+    np.testing.assert_array_equal(np.asarray(halo), want_halo)
+    np.testing.assert_array_equal(np.asarray(cut), want_cut)
 
 
 def test_schedule_spans_nest_on_the_profiler_timeline(tmp_path):
