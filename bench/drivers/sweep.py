@@ -12,7 +12,9 @@ the per-trace schedule cache has been dropped, with the program's
 schedule store switched off, so every capacity of every sweep is
 computed. The window runs whole sweeps and stops at the first sweep
 boundary past ``seconds``; the window over the capacities completed is
-the traffic's ``metric``.
+the traffic's ``metric``. The wall time of every sweep is noted as
+``sweep_ms``, one entry per sweep, each from the end of the one before,
+so the entries add up to the window.
 
 Correct: the vertex, edge, halo and remote-edge counts of the window's
 schedules (every sweep on the products graph, a seeded sample of sweeps
@@ -48,6 +50,7 @@ class Driver:
         from repro.core import trace as rtrace
 
         self._rtrace = rtrace
+        self.ctx = ctx
         self.cfg = ctx.config
         self.engine = ctx.traffic["engine"]
         self.metric = ctx.traffic["metric"]
@@ -80,8 +83,8 @@ class Driver:
         import jax
 
         stats0 = dict(self._rtrace.trace_cache_info()["stats"])
-        kept, sweeps = [], 0
-        t0 = time.perf_counter()
+        kept, sweep_s = [], []
+        t0 = t_prev = time.perf_counter()
         while True:
             with jax.profiler.TraceAnnotation("bench.sweep.clear"):
                 self.trace.clear_schedules()
@@ -90,19 +93,22 @@ class Driver:
             if len(kept) < KEEP_SWEEPS:
                 kept.append(result)
             else:
-                j = int(self.rng.integers(0, sweeps + 1))
+                j = int(self.rng.integers(0, len(sweep_s) + 1))
                 if j < KEEP_SWEEPS:
                     kept[j] = result
-            sweeps += 1
-            if time.perf_counter() - t0 >= seconds:
+            t = time.perf_counter()
+            sweep_s.append(t - t_prev)
+            t_prev = t
+            if t - t0 >= seconds:
                 break
-        elapsed = time.perf_counter() - t0
+        elapsed = t - t0
         kept.append(result)
         stats1 = self._rtrace.trace_cache_info()["stats"]
         delta = {k: stats1[k] - stats0[k] for k in stats1}
-        n = sweeps * len(self.caps)
+        n = len(sweep_s) * len(self.caps)
+        self.ctx.note("sweep_ms", [round(s * 1e3, 3) for s in sweep_s])
         return {"metrics": {self.metric: elapsed / n * 1e3},
-                "attempted": n, "elapsed_s": elapsed, "sweeps": sweeps,
+                "attempted": n, "elapsed_s": elapsed, "sweeps": len(sweep_s),
                 "capacities": n, "unique_pairs": int(self.trace.n_edges),
                 "stats": delta, "kept": kept}
 

@@ -5,7 +5,10 @@ from pathlib import Path
 
 import pytest
 
+import harness
 import traces
+
+DATA = Path(__file__).parent / "data"
 
 RECORD = {
     "devices": {"/device:TPU:0": {
@@ -70,8 +73,7 @@ def test_a_trace_without_a_window_is_an_error():
 
 def test_recorded_tpu_trace():
     """A few forwards of cora.infer recorded on a TPU v5e."""
-    rec = json.loads((Path(__file__).parent / "data" /
-                      "tpu_cora_infer_trace.json").read_text())
+    rec = json.loads((DATA / "tpu_cora_infer_trace.json").read_text())
     lo, hi = traces.window_of(rec)
     busy = traces.busy_ns(rec)
     kernel = traces.op_ns(rec, "gnn_aggregate_combine")
@@ -94,9 +96,51 @@ def test_extract_reads_host_spans_of_a_cpu_trace(tmp_path):
     with traces.recording(str(tmp_path / "tr")):
         with jax.profiler.TraceAnnotation(traces.WINDOW_SPAN):
             with jax.profiler.TraceAnnotation("bench.test.step"):
+                with jax.profiler.TraceAnnotation("repro.test.inner"):
+                    f(a).block_until_ready()
+            with jax.profiler.TraceAnnotation("other.span"):
                 f(a).block_until_ready()
     rec = traces.load(str(tmp_path / "tr"))
     names = [s[0] for s in rec["spans"]]
     assert traces.WINDOW_SPAN in names and "bench.test.step" in names
+    assert "repro.test.inner" in names and "other.span" not in names
     assert rec["devices"] == {}               # the CPU has no TPU plane
     assert traces.busy_ns(rec) == 0.0
+
+
+def test_program_spans_name_the_gaps_and_change_no_reading():
+    """One products.sweep sweep recorded on a TPU v5e, with the program's
+    ``repro.*`` spans kept: every per-layer reading of the cell equals the
+    one from the same trace with only ``bench.*`` spans, and the idle gaps
+    keep their lengths but take the program's span names."""
+    rec = json.loads((DATA / "tpu_products_sweep_trace.json").read_text())
+    bench_only = dict(rec, spans=[s for s in rec["spans"]
+                                  if s[0].startswith("bench.")])
+    assert len(bench_only["spans"]) < len(rec["spans"])
+    benchmark = harness.load_json(harness.ROOT / "BENCHMARK.json")
+    cell, config, traffic = harness.resolve_cell(benchmark, "products.sweep")
+    _, per_layer = harness.cell_metrics(benchmark, "products.sweep")
+    peak = next(iter(harness.load_json(harness.BENCH / "peaks.json")
+                     .values()))
+    record = {"sweeps": 1, "capacities": 4, "unique_pairs": 123_718_280,
+              "stats": {"schedules_ns": 4_100_000_000,
+                        "schedule_upload_ns": 2_400_000_000,
+                        "schedule_upload_bytes": 6_433_350_576,
+                        "schedule_dispatch_ns": 1_700_000,
+                        "schedule_wait_ns": 620_000_000}}
+    readings = []
+    for trace in (rec, bench_only):
+        view = harness.MetricView(config=config, traffic=traffic,
+                                  record=record, trace=trace, peak=peak)
+        readings.append({m["name"]: harness.load_module(
+            harness.BENCH / "metrics" / f"{m['name']}.py",
+            "t_" + m["name"].replace(".", "_")).read(view)
+            for m in per_layer})
+    assert readings[0] == readings[1]
+    assert readings[0]["schedule_device_ms"] > 0
+    assert traces.top_ops(rec) == traces.top_ops(bench_only)
+    gaps, old_gaps = traces.idle_gaps(rec), traces.idle_gaps(bench_only)
+    assert [g[1] for g in gaps] == [g[1] for g in old_gaps]
+    assert {g[0] for g in old_gaps} == {"bench.sweep.schedules"}
+    assert gaps[0][0] == "repro.trace.schedules"
+    assert gaps[1][0] == "repro.trace.schedule.upload"

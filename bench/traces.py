@@ -4,7 +4,8 @@ A trace is reduced in two steps. :func:`extract` turns the profiler's
 ``.xplane.pb`` into a plain, JSON-able record: for each TPU device plane
 the events of its ``XLA Ops`` and ``XLA Modules`` lines, and the host
 spans whose names start with ``bench.`` (the ``jax.profiler.
-TraceAnnotation`` spans the drivers put around each call into a layer).
+TraceAnnotation`` spans the drivers put around each call into a layer)
+or ``repro.`` (the program's own spans inside those calls).
 Everything after that works on the plain record, so the tests can feed
 it a small recorded trace:
 
@@ -14,7 +15,7 @@ it a small recorded trace:
 * :func:`op_ns`: device time of ops by stable name
   (``%gnn_aggregate_combine.2 = ...`` is ``gnn_aggregate_combine``);
 * :func:`idle_gaps`: the device's idle gaps inside the window, each named
-  after the innermost ``bench.*`` span that covers its middle;
+  after the innermost kept host span that covers its middle;
 * :func:`top_ops`: device time by ``program:op`` (``jit_fwd(123)`` is
   the program ``fwd``).
 
@@ -37,6 +38,8 @@ __all__ = ["recording", "extract", "load", "stable_op_name",
            "idle_percent", "op_ns", "idle_gaps", "top_ops"]
 
 WINDOW_SPAN = "bench.window"
+#: Host spans kept by :func:`extract`: the drivers' and the program's.
+SPAN_PREFIXES = ("bench.", "repro.")
 _SUFFIX = re.compile(r"\.\d+$")
 
 
@@ -85,7 +88,7 @@ def extract(profile) -> dict:
             for line in plane.lines:
                 spans.extend([e.name, int(e.start_ns), int(e.duration_ns)]
                              for e in line.events
-                             if e.name.startswith("bench."))
+                             if e.name.startswith(SPAN_PREFIXES))
     spans.sort(key=lambda s: s[1])
     return {"devices": devices, "spans": spans}
 
@@ -168,8 +171,8 @@ def op_ns(rec: dict, name: str) -> float:
 
 def idle_gaps(rec: dict, limit: int = 10) -> list[list]:
     """The longest idle gaps of the first device inside the window, as
-    ``[span name, seconds]``, each named after the innermost ``bench.*``
-    span covering its middle."""
+    ``[span name, seconds]``, each named after the innermost kept host
+    span (``bench.*`` or ``repro.*``) covering its middle."""
     lo, hi = window_of(rec)
     devs = list(rec["devices"].values())
     busy = sorted(_clipped(devs[0]["ops"], lo, hi)) if devs else []
