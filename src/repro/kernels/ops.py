@@ -20,6 +20,7 @@ from .edge_aggregate import fused_aggregate_combine
 from .edge_aggregate_unfused import aggregate_pass, combine_pass
 from .embedding_bag import embedding_bag as _embedding_bag
 from .flash_attention import flash_attention_bhsd
+from .gated_edge import EdgeLayout, edge_layer
 
 
 @partial(jax.jit, static_argnames=("block_n", "block_k", "interpret"))
@@ -48,6 +49,17 @@ def gnn_combine(y_agg: jax.Array, w: jax.Array, *, block_n: int = 256,
     the HyGCN inter-phase analogue, and fusing the passes into one program
     would let XLA elide exactly the traffic being modelled."""
     return combine_pass(y_agg, w, block_n=block_n, interpret=interpret)
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def gated_edge_layer(e: jax.Array, proj: jax.Array, edge_params: dict,
+                     layout: EdgeLayout, *,
+                     interpret: Optional[bool] = None):
+    """GatedGCN's edge half on the fused gated edge kernel, node rows in
+    the layout's slot order: the ``edge_layer_fn`` of
+    :func:`repro.models.gnn.gatedgcn.forward`; see
+    :func:`repro.kernels.gated_edge.edge_layer`."""
+    return edge_layer(e, proj, edge_params, layout, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap",

@@ -27,6 +27,25 @@ def edge_list_aggregate_ref(x: jax.Array, senders: jax.Array,
     return jax.ops.segment_sum(msgs, receivers, num_segments=n_nodes)
 
 
+def gated_edge_layer_ref(e: jax.Array, proj: jax.Array, c: jax.Array,
+                         scale: jax.Array, shift: jax.Array,
+                         senders: jax.Array, receivers: jax.Array,
+                         n_nodes: int):
+    """GatedGCN's edge half over the edge list, float32 at full precision:
+    ``ê = e C + (D h)_rcv + (E h)_snd``, ``e' = e + relu(ê scale + shift)``,
+    and per receiver ``Σ σ(ê) (B h)_snd`` and ``Σ σ(ê)``; ``proj`` is
+    ``[D h | E h | B h]`` by node."""
+    d_h, e_h, b_h = jnp.split(proj, 3, axis=1)
+    e_hat = (jnp.dot(e, c, precision=jax.lax.Precision.HIGHEST)
+             + d_h[receivers] + e_h[senders])
+    e_new = e + jax.nn.relu(e_hat * scale + shift)
+    sig = jax.nn.sigmoid(e_hat)
+    num = jax.ops.segment_sum(sig * b_h[senders], receivers,
+                              num_segments=n_nodes)
+    den = jax.ops.segment_sum(sig, receivers, num_segments=n_nodes)
+    return e_new, num, den
+
+
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                         *, causal: bool = True,
                         window: int | None = None) -> jax.Array:

@@ -30,6 +30,7 @@ from repro.core.conformance import (OperatingPoint, ProgramMeasurement,
                                     conformance_records, measure_program)
 from repro.kernels import edge_aggregate as ea
 from repro.kernels import edge_aggregate_unfused as eu
+from repro.kernels import gated_edge as ge
 from repro.kernels import segment_reduce as sr
 
 #: Cora (2708 nodes) padded to the kernels' 256-node blocks.
@@ -93,6 +94,31 @@ def test_unfused_pair_compiles_at_gcn_cora_layer1(one_chip):
                     _spec(one_chip, (n, f)), _spec(one_chip, (f, t)))
     for compiled in (agg, comb):
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gated_edge_kernel_compiles_at_pattern_size(one_chip):
+    """GatedGCN/PATTERN's edge layer at width 70 (72 sublanes): 1,691
+    blocks of 512 edge slots over 154 node tiles, traced under
+    ``default_matmul_precision("highest")`` as the model runs it, which
+    the kernel's bf16 one-hot products must not take up."""
+    dp, be, node_slots, blocks = 72, 512, 154 * 128, 1691
+    with jax.default_matmul_precision("highest"):
+        compiled = _compile(
+            functools.partial(ge.gated_edge_layer, interpret=False),
+            _spec(one_chip, (blocks, dp, be)),
+            _spec(one_chip, (3 * dp, node_slots), jnp.bfloat16),
+            _spec(one_chip, (6 * dp, node_slots), jnp.bfloat16),
+            _spec(one_chip, (dp, 6 * dp), jnp.bfloat16),
+            _spec(one_chip, (dp, 1)),
+            _spec(one_chip, (dp, 1)),
+            _spec(one_chip, (blocks, 2, be), jnp.int32),
+            _spec(one_chip, (blocks,), jnp.int32),
+            _spec(one_chip, (blocks,), jnp.int32))
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "%gated_edge_layer" in hlo
+    e_out, sums = compiled.out_info
+    assert e_out.shape == (blocks, dp, be)
+    assert sums.shape == (2 * dp, node_slots)
 
 
 def _compile_schedule_engine(sharding, u, n_tiles_pad):
