@@ -99,6 +99,7 @@ _TRACE_STATS = {
     "schedules_ns": 0,       # GraphTrace.schedule(s), any engine, lock wait
     "schedule_upload_ns": 0,     # jax engine: operands to the device
     "schedule_upload_bytes": 0,  # jax engine: nbytes of those operands
+    "schedule_factorization_uploads": 0,  # jax engine: pair factorization
     "schedule_dispatch_ns": 0,   # jax engine: enqueueing the program
     "schedule_wait_ns": 0,       # jax engine: device compute + read-back
     "schedule_hist_mxu": 0,      # jax engine: capacities on the MXU kernel
@@ -855,14 +856,16 @@ class GraphTrace:
         from repro.kernels import segment_reduce
 
         u_snd, u_rcv, u_new_src, mp = self._pair_factorization()
-        mult = np.diff(mp)
+        # One transfer for the whole sweep; the device copy is dropped
+        # when this call returns.
+        fact = segment_reduce.upload_factorization(
+            u_snd, u_rcv, u_new_src, np.diff(mp))
         geos = [(cap, *self._geometry(cap)) for cap in caps]
         n_pad = max(n_tiles for _, n_tiles, _ in geos)
         out = []
         for cap, n_tiles, K in geos:
             _bump_stat("schedule_computes")
-            halo, remote = segment_reduce.schedule_counts(
-                u_snd, u_rcv, u_new_src, mult, K, n_pad)
+            halo, remote = segment_reduce.schedule_counts(*fact, K, n_pad)
             # Host work while the device computes; the read-backs wait.
             boundaries = self._tile_boundaries(n_tiles, K)
             vertex_counts = np.diff(boundaries).astype(np.float64)
@@ -1255,7 +1258,9 @@ def trace_cache_info() -> dict:
     schedule computes/hits, builder invocations — see
     :func:`reset_trace_stats`) and, beside them, the schedule engine's
     span totals: ``schedules_ns`` (whole ``schedule``/``schedules``
-    calls), ``schedule_upload_ns`` and ``schedule_upload_bytes``,
+    calls), ``schedule_upload_ns`` and ``schedule_upload_bytes``, the
+    pair factorization's transfers ``schedule_factorization_uploads``
+    (one per device-engine call, whatever its capacities),
     ``schedule_dispatch_ns`` and ``schedule_wait_ns``, and the histogram
     path per capacity, ``schedule_hist_mxu`` / ``schedule_hist_scatter``
     (device engine)."""

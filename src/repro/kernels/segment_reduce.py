@@ -24,10 +24,12 @@ This module is the accelerator-resident version of that pass:
   to the numpy engine, pinned in tests.  The tile axis is padded to a
   static ``n_tiles_pad`` so a whole capacity sweep shares ONE
   compilation (``GraphTrace.schedules(caps, engine="jax")`` passes the
-  sweep's max tile count).  Its operand upload and its dispatch are
-  timed as the ``repro.trace.schedule.upload`` / ``.dispatch`` spans,
-  the bytes uploaded and the histogram path taken counted, in
-  :mod:`repro.core.trace`'s counters.
+  sweep's max tile count), and :func:`upload_factorization` moves the
+  factorization to the device once for all of them.  The uploads and the
+  dispatch are timed as the ``repro.trace.schedule.upload`` /
+  ``.dispatch`` spans, the bytes uploaded, the factorization transfers
+  and the histogram path taken counted, in :mod:`repro.core.trace`'s
+  counters.
 * :func:`tile_histogram` — the Pallas segment-reduce kernel: grid over
   edge blocks, each block one-hot-expands its tile ids against a
   broadcasted iota into a ``(n_tiles, block)`` matrix and contracts it
@@ -62,6 +64,7 @@ from repro.core.trace import _bump_stat, _span
 from . import resolve_interpret
 
 __all__ = [
+    "upload_factorization",
     "schedule_counts",
     "schedule_counts_pallas",
     "tile_histogram",
@@ -205,30 +208,50 @@ def _schedule_counts_jnp(u_snd, u_rcv, u_new_src, mult, K, n_tiles_pad,
     return _scatter_histograms(tile, halo, cut, n_tiles_pad)
 
 
+def upload_factorization(u_snd, u_rcv, u_new_src, mult):
+    """The pair factorization as device arrays, multiplicities as int32.
+
+    One transfer serves every capacity of a sweep: pass the result to
+    :func:`schedule_counts` for each.  Timed as the
+    ``repro.trace.schedule.upload`` span; the bytes are counted in
+    ``schedule_upload_bytes`` and the transfer itself in
+    ``schedule_factorization_uploads``.
+    """
+    with _span("repro.trace.schedule.upload", "schedule_upload_ns"):
+        fact = (jnp.asarray(u_snd), jnp.asarray(u_rcv),
+                jnp.asarray(u_new_src), jnp.asarray(mult, jnp.int32))
+    _bump_stat("schedule_upload_bytes", sum(a.nbytes for a in fact))
+    _bump_stat("schedule_factorization_uploads")
+    return fact
+
+
 def schedule_counts(u_snd, u_rcv, u_new_src, mult, K, n_tiles_pad: int):
     """(halo_counts, remote_edge_counts) over a padded tile axis, jitted.
 
     Operands are the shared factorization of ``GraphTrace``: unique
     ``(sender, receiver)`` pairs in sender-major order, the new-sender
-    mask, and the per-pair edge multiplicities.  ``K`` is the (dynamic)
-    tile stride, ``n_tiles_pad`` the static padded tile count — tiles
-    beyond ``ceil(V/K)`` come back 0, and a whole capacity sweep padded
-    to its max tile count shares one compilation.  Integer-exact (int32
+    mask, and the per-pair edge multiplicities, as host arrays or as the
+    device arrays of :func:`upload_factorization` (then only ``K`` moves
+    from the host).  ``K`` is the (dynamic) tile stride, ``n_tiles_pad``
+    the static padded tile count — tiles beyond ``ceil(V/K)`` come back
+    0, and a whole capacity sweep padded to its max tile count shares one
+    compilation.  Integer-exact (int32
     totals; counts are bounded by E).  Up to ``MXU_HIST_MAX_TILES`` tiles
     the histograms run on the MXU kernel, above it as scatter-adds; the
     path taken is counted in ``schedule_hist_mxu`` /
     ``schedule_hist_scatter``.
     """
     n_tiles_pad = int(n_tiles_pad)
+    fact = (u_snd, u_rcv, u_new_src, mult)
+    if not all(isinstance(x, jax.Array) for x in fact):
+        fact = upload_factorization(*fact)
     with _span("repro.trace.schedule.upload", "schedule_upload_ns"):
-        u_rcv = jnp.asarray(u_rcv)
-        operands = (jnp.asarray(u_snd), u_rcv, jnp.asarray(u_new_src),
-                    jnp.asarray(mult, jnp.int32), jnp.asarray(K, u_rcv.dtype))
-    _bump_stat("schedule_upload_bytes", sum(a.nbytes for a in operands))
+        K = jnp.asarray(K, fact[1].dtype)
+    _bump_stat("schedule_upload_bytes", K.nbytes)
     _bump_stat("schedule_hist_mxu" if n_tiles_pad <= MXU_HIST_MAX_TILES
                else "schedule_hist_scatter")
     with _span("repro.trace.schedule.dispatch", "schedule_dispatch_ns"):
-        return _schedule_counts_jnp(*operands, n_tiles_pad)
+        return _schedule_counts_jnp(*fact, K, n_tiles_pad)
 
 
 # ---------------------------------------------------------------------------

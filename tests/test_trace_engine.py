@@ -93,19 +93,31 @@ def test_amortized_engine_bitmatches_reference(name):
         assert new.cut_edges == ref.cut_edges
 
 
+@pytest.mark.parametrize("sweep", [True, False], ids=["sweep", "one_by_one"])
 @pytest.mark.parametrize("name", sorted(DATASET_PARAMS))
-def test_jax_engine_bitmatches_reference(name):
+def test_jax_engine_bitmatches_reference(name, sweep):
+    """A multi-capacity sweep (one factorization upload for all its
+    capacities) and lone ``schedule`` calls both match the oracle and the
+    numpy engine entry for entry."""
     trace = resolve_trace_dataset(name, DATASET_PARAMS[name])
     oracle = _reference_trace(name, trace)
     trace.clear_schedules()
     caps = _pow2_caps(trace.n_nodes)[:3]
-    scheds = trace.schedules(caps, engine="jax")
-    for cap, sched in zip(caps, scheds):
+    if sweep:
+        scheds = trace.schedules(caps, engine="jax")
+    else:
+        scheds = [trace.schedule(cap, engine="jax") for cap in caps]
+    trace.clear_schedules()
+    host = trace.schedules(caps, engine="numpy")
+    for cap, sched, want in zip(caps, scheds, host):
         ref = oracle.schedule_reference(cap)
         for f in COUNT_FIELDS:
             np.testing.assert_array_equal(
                 getattr(sched, f), getattr(ref, f),
                 err_msg=f"{name} cap={cap} field={f}")
+            np.testing.assert_array_equal(
+                getattr(sched, f), getattr(want, f),
+                err_msg=f"{name} cap={cap} field={f} (numpy engine)")
         # disk-less schedules still answer cache-hit queries (lazy pairs)
         np.testing.assert_array_equal(sched.cache_hit_fraction(0.2),
                                       ref.cache_hit_fraction(0.2))
@@ -720,9 +732,10 @@ def test_schedule_spans_and_upload_counters(engine):
     after = trace_cache_info()["stats"]
     d = {k: after[k] - before[k] for k in after}
     on_device = engine == "jax"
-    # Per capacity: int32 senders, receivers and multiplicities and the
-    # bool new-sender mask (13 B per pair), plus the int32 stride.
-    assert d["schedule_upload_bytes"] == on_device * len(caps) * (13 * U + 4)
+    # Once per sweep: int32 senders, receivers and multiplicities and the
+    # bool new-sender mask (13 B per pair); per capacity the int32 stride.
+    assert d["schedule_upload_bytes"] == on_device * (13 * U + 4 * len(caps))
+    assert d["schedule_factorization_uploads"] == on_device
     for key in _DEVICE_SPAN_COUNTERS:
         assert (d[key] > 0) == on_device, key
     assert d["schedules_ns"] >= sum(d[k] for k in _DEVICE_SPAN_COUNTERS)
@@ -782,8 +795,62 @@ def test_schedule_spans_nest_on_the_profiler_timeline(tmp_path):
     outer = [s for s in spans if s[0] == "repro.trace.schedules"]
     inner = [s for s in spans if s[0].startswith("repro.trace.schedule.")]
     assert len(outer) == 1
+    # One upload of the factorization, then per capacity the stride's
+    # upload, the dispatch and the wait.
     assert sorted(s[0] for s in inner) == sorted(
-        2 * ["repro.trace.schedule.upload", "repro.trace.schedule.dispatch",
-             "repro.trace.schedule.wait"])
+        ["repro.trace.schedule.upload"]
+        + 2 * ["repro.trace.schedule.upload", "repro.trace.schedule.dispatch",
+               "repro.trace.schedule.wait"])
     _, lo, hi = outer[0]
     assert all(lo <= start and end <= hi for _, start, end in inner)
+
+
+def _stat_delta(fn):
+    before = dict(trace_cache_info()["stats"])
+    out = fn()
+    after = trace_cache_info()["stats"]
+    return out, {k: after[k] - before[k] for k in after}
+
+
+@pytest.mark.parametrize("call", ["sweep", "single"])
+def test_factorization_uploads_once_per_call(call):
+    """The device engine moves the pair factorization once per call and
+    serves every capacity of the call from that copy."""
+    trace = _simple_trace()
+    trace._pair_factorization()
+    caps = [8, 16, 32] if call == "sweep" else [8]
+    if call == "sweep":
+        _, d = _stat_delta(lambda: trace.schedules(caps, engine="jax"))
+    else:
+        _, d = _stat_delta(lambda: trace.schedule(caps[0], engine="jax"))
+    assert d["schedule_factorization_uploads"] == 1
+    assert d["schedule_computes"] == len(caps)
+    _, d = _stat_delta(lambda: trace.schedules(caps, engine="numpy"))
+    assert d["schedule_factorization_uploads"] == 0
+
+
+@pytest.mark.parametrize("above", [False, True], ids=["mxu", "scatter"])
+def test_schedule_counts_on_device_arrays_moves_only_the_stride(above):
+    """Given the device arrays of ``upload_factorization``,
+    ``schedule_counts`` uploads only ``K`` (4 B) and answers exactly as
+    it does from the host arrays, on either histogram path."""
+    from repro.kernels import segment_reduce as sr
+
+    n_tiles_pad = sr.MXU_HIST_MAX_TILES + 1 if above else 24
+    trace = _simple_trace()
+    u_snd, u_rcv, u_new_src, mp = trace._pair_factorization()
+    mult = np.diff(mp)
+    fact, d = _stat_delta(
+        lambda: sr.upload_factorization(u_snd, u_rcv, u_new_src, mult))
+    assert d["schedule_upload_bytes"] == 13 * trace.n_edges
+    assert d["schedule_factorization_uploads"] == 1
+    for K in (4, 12):
+        dev, d = _stat_delta(lambda: sr.schedule_counts(*fact, K, n_tiles_pad))
+        assert d["schedule_upload_bytes"] == 4
+        assert d["schedule_factorization_uploads"] == 0
+        host, d = _stat_delta(lambda: sr.schedule_counts(
+            u_snd, u_rcv, u_new_src, mult, K, n_tiles_pad))
+        assert d["schedule_upload_bytes"] == 13 * trace.n_edges + 4
+        assert d["schedule_factorization_uploads"] == 1
+        for got, want in zip(dev, host):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -457,6 +457,7 @@ def test_reset_trace_stats_zeroes_all_counters():
                           "schedule_cache_hits", "schedule_disk_hits",
                           "trace_builds", "schedules_ns",
                           "schedule_upload_ns", "schedule_upload_bytes",
+                          "schedule_factorization_uploads",
                           "schedule_dispatch_ns", "schedule_wait_ns",
                           "schedule_hist_mxu", "schedule_hist_scatter"}
     assert all(v == 0 for v in stats.values())
