@@ -22,10 +22,7 @@ b. front door  — ``examples/scenarios/comparison.json`` through
 c. schedules   — on the same 10^7-edge trace, ``GraphTrace.schedules(caps,
                  engine="jax")`` over 16 power-of-two capacities gives
                  counts bit-identical to the numpy engine and to what the
-                 server computed; the Pallas histogram
-                 (``schedule_counts_pallas``) on a 10^6-edge trace matches
-                 the numpy engine at every capacity whose tile count the
-                 kernel accepts.
+                 server computed.
 d. GCN-Cora    — layer 1 (1433->16) and layer 2 (16->7) of
                  ``configs/gcn_cora`` on a Cora-sized graph (2708 nodes,
                  10556 edges plus self-loops, symmetric norm, padded to
@@ -59,12 +56,9 @@ ROOT = Path(__file__).resolve().parent
 NUMERICS_REL_TOL = 1e-5
 #: Trace the server, the serial oracle and the device engines share.
 BIG_TRACE = {"n_nodes": 1_000_000.0, "n_edges": 10_000_000.0, "alpha": 1.6}
-#: Trace of the Pallas histogram phase.
-HIST_TRACE = {"n_nodes": 100_000.0, "n_edges": 1_000_000.0, "alpha": 1.6}
 #: Capacities the server answers; a subset of the 16-capacity sweep.
 SERVED_CAPS = (1024, 4096, 16384, 65536)
 SWEEP_CAPS = tuple(2 ** k for k in range(4, 20))
-HIST_CAPS = tuple(2 ** k for k in range(2, 18))
 CORA_NODES, CORA_EDGES, CORA_PADDED = 2708, 10556, 2816
 
 
@@ -188,11 +182,7 @@ def _same_counts(a: dict, b: dict) -> bool:
 
 
 def phase_schedules(seed: int, served_counts: dict):
-    import jax
-    import numpy as np
-
     from repro.core.trace import resolve_trace_dataset
-    from repro.kernels import segment_reduce as sr
 
     trace = resolve_trace_dataset("power_law", {**BIG_TRACE,
                                                 "seed": float(seed)})
@@ -208,32 +198,8 @@ def phase_schedules(seed: int, served_counts: dict):
     for cap, counts in served_counts.items():
         check(_same_counts(device[cap], counts),
               f"engine=jax differs from the served schedule at cap {cap}")
-
-    small = resolve_trace_dataset("power_law", {**HIST_TRACE,
-                                                "seed": float(seed)})
-    u_snd, u_rcv, u_new_src, mp = small._pair_factorization()
-    mult = np.diff(mp)
-    accepted, refused = [], []
-    for cap, ref in zip(HIST_CAPS, small.schedules(HIST_CAPS)):
-        try:
-            halo, cut = sr.schedule_counts_pallas(
-                u_snd, u_rcv, u_new_src, mult, ref.K, ref.n_tiles)
-        except ValueError as exc:
-            check("VMEM" in str(exc), f"histogram refused cap {cap}: {exc}")
-            refused.append(cap)
-            continue
-        halo, cut = jax.block_until_ready((halo, cut))
-        check(np.array_equal(np.asarray(halo, np.float64), ref.halo_counts)
-              and np.array_equal(np.asarray(cut, np.float64),
-                                 ref.remote_edge_counts),
-              f"Pallas histogram differs from numpy at capacity {cap}")
-        accepted.append(cap)
-    check(accepted, "the Pallas histogram accepted no capacity")
     sizes = {"trace_edges": int(trace.n_edges), "sweep_caps": len(SWEEP_CAPS),
-             "max_tiles": int(-(-trace.n_nodes // SWEEP_CAPS[0])),
-             "hist_trace_edges": int(small.n_edges),
-             "hist_unique_pairs": int(u_snd.size),
-             "hist_caps_accepted": accepted, "hist_caps_refused": refused}
+             "max_tiles": int(-(-trace.n_nodes // SWEEP_CAPS[0]))}
     return sizes, None
 
 
@@ -342,8 +308,7 @@ def main(argv=None) -> int:
         "vs serial evaluate_scenarios",
         lambda: phase_front_door_and_serve(args.seed))
     run_phase(clock, kind, "schedules",
-              "GraphTrace.schedules(engine='jax') vs numpy + "
-              "schedule_counts_pallas",
+              "GraphTrace.schedules(engine='jax') vs numpy",
               lambda: phase_schedules(args.seed, served_counts))
     run_phase(clock, kind, "gcn_cora",
               "ops.gnn_aggregate_combine and gnn_aggregate->gnn_combine "

@@ -31,13 +31,18 @@ edge-multiplicity prefix — makes the deduplicated ``(dst_tile, source)``
 pairs of any capacity appear as single contiguous runs (tile monotone
 within each sender segment), so a capacity sweep costs **one sort plus
 one O(U) boundary-flag pass per capacity** (U = unique pairs) instead
-of a fresh ``np.unique`` sort each time.
-:meth:`GraphTrace.schedules` batches a whole capacity sweep;
-:meth:`GraphTrace.schedule_reference` keeps the per-capacity PR-4
-``np.unique`` algorithm as the bit-exactness oracle.  A jitted JAX
-engine (``engine="jax"``, :mod:`repro.kernels.segment_reduce`, with a
-Pallas segment-reduce kernel) and a content-addressed on-disk cache
-(:mod:`repro.core.schedule_cache`) ride on the same factorization.
+of a fresh ``np.unique`` sort each time.  The pass is written once, as
+:func:`pair_runs`.  :meth:`GraphTrace.schedules` batches a whole
+capacity sweep through one of three engines — ``"numpy"`` (the pass on
+the host), ``"sharded"`` (the pass per chunk on a thread pool,
+:mod:`repro.distributed.trace_shard`) and ``"jax"`` (the pass on the
+device, :mod:`repro.kernels.segment_reduce`) — each a count function
+whose per-tile counts one assembly step turns into a
+:class:`TraceSchedule`.  :meth:`GraphTrace.schedule_reference` keeps the
+per-capacity PR-4 ``np.unique`` algorithm as the bit-exactness oracle,
+and a content-addressed on-disk cache (:mod:`repro.core.schedule_cache`)
+rides on the same factorization.  The engine's counters and spans live
+in the leaf module :mod:`repro.trace_stats`.
 
 :class:`~repro.core.compose.TiledGraphModel` accepts a trace as an
 alternative schedule source; the scenario front door exposes it as the
@@ -51,14 +56,14 @@ from __future__ import annotations
 
 import functools
 import json
-import sys
 import threading
-import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping, Optional, Sequence
 
 import numpy as np
+
+from repro.trace_stats import _bump_stat, _span, reset_trace_stats, trace_stats
 
 __all__ = [
     "GraphTrace",
@@ -80,84 +85,11 @@ __all__ = [
 CORA_V = 2708
 CORA_E = 10556
 
-_ENGINES = ("numpy", "jax", "sharded")
-
-#: Process-wide work counters (observability, not behaviour): how many
-#: edge-list sorts, schedule computations, schedule-cache hits, and
-#: builder invocations actually happened.  The §15 tuner's cache-reuse
-#: regression gates on ``factorizations`` — a multi-capacity tune must
-#: never silently re-sort the edge list per candidate.  Beside them, the
-#: span totals of the schedule engine (:class:`_span`): host nanoseconds
-#: summed over calls (over threads too, so they can exceed wall time),
-#: and the bytes the device engine uploads.  All values are integers.
-_TRACE_STATS = {
-    "factorizations": 0,     # actual sorts (not disk-cache rehydrations)
-    "schedule_computes": 0,  # per-capacity O(U) boundary-flag passes
-    "schedule_cache_hits": 0,  # per-trace LRU hits
-    "schedule_disk_hits": 0,   # on-disk schedule_cache hits
-    "trace_builds": 0,       # dataset builder invocations (cold resolves)
-    "schedules_ns": 0,       # GraphTrace.schedule(s), any engine, lock wait
-    "schedule_upload_ns": 0,     # jax engine: operands to the device
-    "schedule_upload_bytes": 0,  # jax engine: nbytes of those operands
-    "schedule_factorization_uploads": 0,  # jax engine: pair factorization
-    "schedule_dispatch_ns": 0,   # jax engine: enqueueing the program
-    "schedule_wait_ns": 0,       # jax engine: device compute + read-back
-    "schedule_hist_mxu": 0,      # jax engine: capacities on the MXU kernel
-    "schedule_hist_scatter": 0,  # jax engine: capacities on scatter-adds
-}
-
-#: Guards ``_TRACE_STATS`` read-modify-write cycles.  The serve engine
-#: (DESIGN.md §18) hammers the counters from many request threads; an
-#: unguarded ``+=`` loses increments under the GIL's bytecode-boundary
-#: preemption.
-_STATS_LOCK = threading.Lock()
-
 #: Guards the process-wide resolved-trace LRU (``_TRACE_CACHE``), its
 #: byte budget, and the dataset registry.  Reentrant because a cold
 #: ``resolve_trace_dataset`` holds it across the builder call, and the
 #: builder may consult registry metadata.
 _CACHE_LOCK = threading.RLock()
-
-
-def _bump_stat(name: str, n: int = 1) -> None:
-    with _STATS_LOCK:
-        _TRACE_STATS[name] += n
-
-
-class _span:
-    """Context manager: add the block's host nanoseconds to the integer
-    ``_TRACE_STATS[counter]`` and mark it as ``name`` on the profiler's
-    host timeline (a ``jax.profiler.TraceAnnotation``, which records only
-    while a profiler session runs), so device gaps line up with it.
-
-    jax is only looked up, never imported: a process that has not
-    imported it runs no profiler session, and the numpy engine path
-    stays free of it."""
-
-    __slots__ = ("_counter", "_annotation", "_t0")
-
-    def __init__(self, name: str, counter: str) -> None:
-        self._counter = counter
-        jax = sys.modules.get("jax")
-        self._annotation = (None if jax is None
-                            else jax.profiler.TraceAnnotation(name))
-
-    def __enter__(self) -> None:
-        if self._annotation is not None:
-            self._annotation.__enter__()
-        self._t0 = time.perf_counter_ns()
-
-    def __exit__(self, *exc) -> None:
-        _bump_stat(self._counter, time.perf_counter_ns() - self._t0)
-        if self._annotation is not None:
-            self._annotation.__exit__(*exc)
-
-
-def reset_trace_stats() -> None:
-    """Zero the process-wide trace work counters (see trace_cache_info)."""
-    with _STATS_LOCK:
-        for key in _TRACE_STATS:
-            _TRACE_STATS[key] = 0
 
 
 def _f64(x) -> np.ndarray:
@@ -302,6 +234,110 @@ class TraceSchedule:
             "cache_hit_fraction_min": float(hit.min()),
             "cache_hit_fraction_max": float(hit.max()),
         }
+
+
+# ---------------------------------------------------------------------------
+# The schedule engines (DESIGN.md §13): one boundary-flag pass, three ways
+# to run it.  An engine is a count function ``(fact, geos, n_pad)``: given
+# the pair factorization, the sweep's ``(cap, n_tiles, K)`` list and its
+# largest tile count, it yields per capacity a thunk returning the int64
+# ``(halo, remote)`` per-tile counts; GraphTrace._assemble does the rest.
+# ---------------------------------------------------------------------------
+
+def pair_runs(fact: tuple, K: int, n_tiles: int, lo: int = 0,
+              hi: Optional[int] = None) -> tuple[np.ndarray, np.ndarray,
+                                                 np.ndarray, np.ndarray]:
+    """The O(U) boundary-flag pass over pairs ``[lo, hi)`` of ``fact``.
+
+    ``fact`` is :meth:`GraphTrace._pair_factorization`'s ``(u_snd, u_rcv,
+    u_new_src, mult_prefix)``.  A ``(dst_tile, source)`` pair run starts
+    wherever the sender changes or the tile of the (per-sender ascending)
+    receiver does, and at ``lo``, which must be a new-sender position;
+    its edge multiplicity is a difference of the multiplicity prefix.  A
+    run is remote when its source lives outside the destination tile.
+
+    Returns ``(halo, remote, pair_tile, pair_count)``: the ``(n_tiles,)``
+    int64 unique-remote-source and cut-edge counts, and the runs' tiles
+    and multiplicities in source-major order.  Cut edges accumulate in
+    int64 (``np.add.at``): a weighted ``np.bincount`` sums in float64 and
+    rounds past 2^53.
+    """
+    u_snd, u_rcv, u_new_src, mp = fact
+    hi = u_snd.size if hi is None else hi
+    n = hi - lo
+    if n <= 0:
+        z = np.zeros(0, dtype=np.int64)
+        return (np.zeros(n_tiles, dtype=np.int64),
+                np.zeros(n_tiles, dtype=np.int64), z, z)
+    Kd = u_rcv.dtype.type(K)
+    tile_u = u_rcv[lo:hi] // Kd
+    boundary = np.empty(n, dtype=bool)
+    boundary[0] = True
+    np.logical_or(u_new_src[lo + 1:hi], tile_u[1:] != tile_u[:-1],
+                  out=boundary[1:])
+    pidx = np.flatnonzero(boundary)
+    nxt = np.empty(pidx.size, dtype=np.int64)
+    nxt[:-1] = pidx[1:]
+    nxt[-1] = n
+    pair_tile = tile_u[pidx].astype(np.int64, copy=False)
+    pair_count = mp[lo + nxt] - mp[lo + pidx]
+    remote = (u_snd[lo + pidx] // Kd) != tile_u[pidx]
+    remote_tile = pair_tile[remote]
+    halo = np.bincount(remote_tile, minlength=n_tiles).astype(
+        np.int64, copy=False)
+    cut = np.zeros(n_tiles, dtype=np.int64)
+    np.add.at(cut, remote_tile, pair_count[remote])
+    return halo, cut, pair_tile, pair_count
+
+
+def _halo_and_cut(fact, K, n_tiles):
+    halo, cut, _, _ = pair_runs(fact, K, n_tiles)
+    return halo, cut
+
+
+def _numpy_engine(fact, geos, n_pad):
+    """The pass on the host, once per capacity."""
+    for _, n_tiles, K in geos:
+        yield functools.partial(_halo_and_cut, fact, K, n_tiles)
+
+
+def _sharded_engine(fact, geos, n_pad):
+    """The pass split at new-sender boundaries and run per shard on a
+    thread pool (:func:`repro.distributed.trace_shard.
+    sharded_schedule_counts`)."""
+    from repro.distributed import trace_shard
+
+    for _, n_tiles, K in geos:
+        yield functools.partial(trace_shard.sharded_schedule_counts,
+                                fact, K, n_tiles)
+
+
+def _read_back(halo, cut, n_tiles):
+    with _span("repro.trace.schedule.wait", "schedule_wait_ns"):
+        halo, cut = np.asarray(halo), np.asarray(cut)
+    return halo[:n_tiles], cut[:n_tiles]
+
+
+def _jax_engine(fact, geos, n_pad):
+    """The pass on the device (:mod:`repro.kernels.segment_reduce`): one
+    upload of the factorization for the whole sweep, then per capacity
+    the dispatch when the generator is advanced and the read-back in the
+    thunk, so the host work of _assemble overlaps the device's.  The
+    sweep shares one compilation over ``n_pad`` padded tiles; the device
+    copy is dropped when the generator is."""
+    from repro.kernels import segment_reduce
+
+    u_snd, u_rcv, u_new_src, mp = fact
+    dev = segment_reduce.upload_factorization(u_snd, u_rcv, u_new_src,
+                                              np.diff(mp))
+    for _, n_tiles, K in geos:
+        halo, cut = segment_reduce.schedule_counts(*dev, K, n_pad)
+        yield functools.partial(_read_back, halo, cut, n_tiles)
+
+
+#: Engine name -> count function (see the section comment above).
+_ENGINES = {"numpy": _numpy_engine, "jax": _jax_engine,
+            "sharded": _sharded_engine}
 
 
 class GraphTrace:
@@ -652,40 +688,12 @@ class GraphTrace:
         return np.minimum(np.arange(n_tiles + 1, dtype=np.int64) * K,
                           self.n_nodes)
 
-    def _pair_runs(self, K: int) -> tuple[np.ndarray, np.ndarray,
-                                          np.ndarray, np.ndarray]:
-        """(pair_tile, pair_count, remote, src_at_run) for stride K.
-
-        One O(U) pass over the shared factorization: a ``(dst_tile,
-        source)`` pair starts wherever the sender changes or the tile of
-        the (per-sender ascending) receiver does; its edge multiplicity
-        is a difference of the precomputed multiplicity prefix.
-        """
-        u_snd, u_rcv, u_new_src, mp = self._pair_factorization()
-        U = u_snd.size
-        if not U:
-            z = np.zeros(0, dtype=np.int64)
-            return z, z, np.zeros(0, dtype=bool), z
-        Kd = u_rcv.dtype.type(K)
-        tile_u = u_rcv // Kd
-        boundary = np.empty(U, dtype=bool)
-        boundary[0] = True
-        np.logical_or(u_new_src[1:], tile_u[1:] != tile_u[:-1],
-                      out=boundary[1:])
-        pidx = np.flatnonzero(boundary)
-        nxt = np.empty(pidx.size, dtype=np.int64)
-        nxt[:-1] = pidx[1:]
-        nxt[-1] = U
-        pair_tile = tile_u[pidx].astype(np.int64, copy=False)
-        pair_count = mp[nxt] - mp[pidx]
-        src = u_snd[pidx]
-        remote = (src // Kd) != tile_u[pidx]
-        return pair_tile, pair_count, remote, src
-
-    def _pairs_for(self, K: int) -> tuple[np.ndarray, np.ndarray]:
+    def _pairs_for(self, n_tiles: int, K: int) -> tuple[np.ndarray,
+                                                        np.ndarray]:
         """Deduplicated ``(dst_tile, source)`` pairs for stride K, in
         source-major order (tile ascending within each source)."""
-        pair_tile, pair_count, _, _ = self._pair_runs(K)
+        _, _, pair_tile, pair_count = pair_runs(
+            self._pair_factorization(), K, n_tiles)
         return pair_tile, pair_count
 
     @staticmethod
@@ -696,35 +704,23 @@ class GraphTrace:
                              f"for a trace schedule, got {tile_vertices!r}")
         return cap
 
-    def _compute_schedule(self, cap: int) -> TraceSchedule:
-        """One capacity via the shared factorization: O(U) after the sort."""
+    def _assemble(self, cap: int, n_tiles: int, K: int,
+                  counts: Callable[[], tuple]) -> TraceSchedule:
+        """One computed schedule: the geometry from the CSR row pointer,
+        then the engine's ``(halo, remote)`` counts from the ``counts``
+        thunk (the device engine's read-back waits here, after the host
+        work), with the pair runs derived lazily for cache statistics."""
         _bump_stat("schedule_computes")
-        n_tiles, K = self._geometry(cap)
         boundaries = self._tile_boundaries(n_tiles, K)
         vertex_counts = np.diff(boundaries).astype(np.float64)
         edge_counts = np.diff(self.row_ptr[boundaries]).astype(np.float64)
-        pair_tile, pair_count, remote, _ = self._pair_runs(K)
-        if pair_tile.size:
-            # A pair is remote when its source lives outside the
-            # destination tile; summing the run multiplicities recovers
-            # the (pre-dedup) cut edges.
-            halo_counts = np.bincount(
-                pair_tile[remote], minlength=n_tiles).astype(np.float64)
-            # int64 accumulation, float64 only at the boundary: a
-            # weighted bincount rounds in float64 *while summing*, which
-            # is lossier than one final cast for totals near 2^53.
-            rec = np.zeros(n_tiles, dtype=np.int64)
-            np.add.at(rec, pair_tile[remote],
-                      np.asarray(pair_count[remote], dtype=np.int64))
-            remote_edge_counts = rec.astype(np.float64)
-        else:
-            halo_counts = np.zeros(n_tiles, dtype=np.float64)
-            remote_edge_counts = np.zeros(n_tiles, dtype=np.float64)
+        halo, remote = counts()
         return TraceSchedule(
-            n_tiles=int(n_tiles), capacity=cap, K=int(K),
+            n_tiles=int(n_tiles), capacity=int(cap), K=int(K),
             vertex_counts=vertex_counts, edge_counts=edge_counts,
-            halo_counts=halo_counts, remote_edge_counts=remote_edge_counts,
-            _pair_source=functools.partial(self._pairs_for, K))
+            halo_counts=halo.astype(np.float64),
+            remote_edge_counts=remote.astype(np.float64),
+            _pair_source=functools.partial(self._pairs_for, n_tiles, K))
 
     # -- schedule cache plumbing ------------------------------------------
     def _cached_schedule(self, cap: int) -> Optional[TraceSchedule]:
@@ -766,7 +762,8 @@ class GraphTrace:
             vertex_counts=d["vertex_counts"], edge_counts=d["edge_counts"],
             halo_counts=d["halo_counts"],
             remote_edge_counts=d["remote_edge_counts"],
-            _pair_source=functools.partial(self._pairs_for, d["K"]))
+            _pair_source=functools.partial(self._pairs_for, d["n_tiles"],
+                                           d["K"]))
         self._remember_schedule(cap, sched, to_disk=False)
         return sched
 
@@ -787,30 +784,10 @@ class GraphTrace:
         by the stride, per-tile edge counts are CSR row-pointer
         differences at the tile boundaries, and halo / multiplicity
         counts are one boundary-flag pass over the shared sender-major
-        unique-pair factorization (DESIGN.md §13).  ``engine="jax"`` routes the
-        segmented counts through the jitted path in
-        :mod:`repro.kernels.segment_reduce` (bit-identical integers).
+        unique-pair factorization (DESIGN.md §13).  A one-capacity
+        :meth:`schedules` call, on any of its engines.
         """
-        with _span("repro.trace.schedules", "schedules_ns"):
-            cap = self._validate_cap(tile_vertices)
-            if engine not in _ENGINES:
-                raise ValueError(f"unknown trace engine {engine!r}; "
-                                 f"expected one of {_ENGINES}")
-            # Held across the compute so concurrent callers of the same
-            # capacity see exactly one schedule_computes bump (the §18
-            # serve metrics count on it) instead of racing duplicate
-            # passes.
-            with self._lock:
-                sched = self._cached_schedule(cap)
-                if sched is None:
-                    if engine == "jax":
-                        sched = self._compute_schedules_jax([cap])[0]
-                    elif engine == "sharded":
-                        sched = self._compute_schedules_sharded([cap])[0]
-                    else:
-                        sched = self._compute_schedule(cap)
-                    self._remember_schedule(cap, sched)
-                return sched
+        return self.schedules([tile_vertices], engine=engine)[0]
 
     def schedules(self, tile_vertices: Sequence, *,
                   engine: str = "numpy") -> tuple[TraceSchedule, ...]:
@@ -819,18 +796,24 @@ class GraphTrace:
         The whole sweep costs one shared (cached) sorted-edge
         factorization plus a linear segmented pass per *distinct*
         capacity; results come back in input order (duplicates allowed)
-        and land in the same per-trace LRU that :meth:`schedule` uses.
+        and land in the per-trace LRU.  ``engine`` names the count
+        function of :data:`_ENGINES` (``"numpy"``, ``"jax"`` or
+        ``"sharded"``); every engine gives bit-identical integers.
         """
         with _span("repro.trace.schedules", "schedules_ns"):
             caps = [self._validate_cap(c) for c in tile_vertices]
             if engine not in _ENGINES:
                 raise ValueError(f"unknown trace engine {engine!r}; "
-                                 f"expected one of {_ENGINES}")
+                                 f"expected one of {tuple(_ENGINES)}")
             # Results are held locally so a sweep wider than the schedule
             # LRU still returns every schedule (the LRU may evict early
             # entries while later capacities compute).
             found: dict[int, TraceSchedule] = {}
             missing = []
+            # Held across the compute so concurrent callers of the same
+            # capacity see exactly one schedule_computes bump (the §18
+            # serve metrics count on it) instead of racing duplicate
+            # passes.
             with self._lock:
                 for cap in dict.fromkeys(caps):
                     sched = self._cached_schedule(cap)
@@ -839,70 +822,16 @@ class GraphTrace:
                     else:
                         found[cap] = sched
                 if missing:
-                    if engine == "jax":
-                        computed = self._compute_schedules_jax(missing)
-                    elif engine == "sharded":
-                        computed = self._compute_schedules_sharded(missing)
-                    else:
-                        computed = [self._compute_schedule(c)
-                                    for c in missing]
-                    for cap, sched in zip(missing, computed):
+                    geos = [(cap, *self._geometry(cap)) for cap in missing]
+                    n_pad = max(n_tiles for _, n_tiles, _ in geos)
+                    counts = _ENGINES[engine](self._pair_factorization(),
+                                              geos, n_pad)
+                    computed = [self._assemble(*geo, c)
+                                for geo, c in zip(geos, counts)]
+                    for (cap, _, _), sched in zip(geos, computed):
                         self._remember_schedule(cap, sched)
                         found[cap] = sched
             return tuple(found[c] for c in caps)
-
-    def _compute_schedules_jax(self, caps: Sequence[int]) -> list[TraceSchedule]:
-        """The jitted engine: one compile per sweep (padded tile axis)."""
-        from repro.kernels import segment_reduce
-
-        u_snd, u_rcv, u_new_src, mp = self._pair_factorization()
-        # One transfer for the whole sweep; the device copy is dropped
-        # when this call returns.
-        fact = segment_reduce.upload_factorization(
-            u_snd, u_rcv, u_new_src, np.diff(mp))
-        geos = [(cap, *self._geometry(cap)) for cap in caps]
-        n_pad = max(n_tiles for _, n_tiles, _ in geos)
-        out = []
-        for cap, n_tiles, K in geos:
-            _bump_stat("schedule_computes")
-            halo, remote = segment_reduce.schedule_counts(*fact, K, n_pad)
-            # Host work while the device computes; the read-backs wait.
-            boundaries = self._tile_boundaries(n_tiles, K)
-            vertex_counts = np.diff(boundaries).astype(np.float64)
-            edge_counts = np.diff(self.row_ptr[boundaries]).astype(np.float64)
-            with _span("repro.trace.schedule.wait", "schedule_wait_ns"):
-                halo, remote = np.asarray(halo), np.asarray(remote)
-            out.append(TraceSchedule(
-                n_tiles=int(n_tiles), capacity=int(cap), K=int(K),
-                vertex_counts=vertex_counts, edge_counts=edge_counts,
-                halo_counts=halo[:n_tiles].astype(np.float64),
-                remote_edge_counts=remote[:n_tiles].astype(np.float64),
-                _pair_source=functools.partial(self._pairs_for, K)))
-        return out
-
-    def _compute_schedules_sharded(self, caps: Sequence[int]
-                                   ) -> list[TraceSchedule]:
-        """The sharded engine: the O(U) boundary-flag pass split at
-        new-sender boundaries and run per shard (bit-identical partial
-        bincounts summed; :mod:`repro.distributed.trace_shard`)."""
-        from repro.distributed import trace_shard
-
-        out = []
-        for cap in caps:
-            _bump_stat("schedule_computes")
-            n_tiles, K = self._geometry(cap)
-            boundaries = self._tile_boundaries(n_tiles, K)
-            halo, remote = trace_shard.sharded_schedule_counts(
-                self._pair_factorization(), K, n_tiles)
-            out.append(TraceSchedule(
-                n_tiles=int(n_tiles), capacity=int(cap), K=int(K),
-                vertex_counts=np.diff(boundaries).astype(np.float64),
-                edge_counts=np.diff(
-                    self.row_ptr[boundaries]).astype(np.float64),
-                halo_counts=halo.astype(np.float64),
-                remote_edge_counts=remote.astype(np.float64),
-                _pair_source=functools.partial(self._pairs_for, K)))
-        return out
 
     def schedule_reference(self, tile_vertices) -> TraceSchedule:
         """The PR-4 per-capacity algorithm, kept verbatim as the oracle.
@@ -1268,10 +1197,8 @@ def trace_cache_info() -> dict:
         entries = len(_TRACE_CACHE)
         nbytes = int(sum(t.nbytes for t in _TRACE_CACHE.values()))
         budget = int(_TRACE_CACHE_BUDGET_BYTES)
-    with _STATS_LOCK:
-        stats = dict(_TRACE_STATS)
     return {"entries": entries, "bytes": nbytes,
-            "budget_bytes": budget, "stats": stats}
+            "budget_bytes": budget, "stats": trace_stats()}
 
 
 def resolve_trace_dataset(name: str,

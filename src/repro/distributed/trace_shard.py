@@ -29,14 +29,16 @@ module shards all three stages (DESIGN.md §14) while keeping the result
    unchanged by PR 5's O(U) per-capacity pass.
 
 3. **Sharded CSR + halo counting.**  From the factorization the CSR
-   row pointer is an O(U) weighted bincount
+   row pointer is an O(U) int64 accumulation
    (:meth:`GraphTrace.from_factorization`) — the E-sized receiver-major
-   sort never happens at all.  Per-capacity tile/halo counts split the
-   factorization at *new-sender boundaries* (every deduplicated
-   ``(dst_tile, source)`` run lives wholly inside one sender segment),
-   run the boundary-flag pass per chunk, and sum the partial integer
-   bincounts — bit-identical to the single-host pass by construction
-   (:func:`sharded_schedule_counts`, ``engine="sharded"``).
+   sort never happens at all.  The sharded engine (``engine="sharded"``,
+   :func:`sharded_schedule_counts`) is one of the three count functions
+   of :meth:`GraphTrace.schedules`: it splits the factorization at
+   *new-sender boundaries* (every deduplicated ``(dst_tile, source)`` run
+   lives wholly inside one sender segment), runs the single-host
+   boundary-flag pass (:func:`repro.core.trace.pair_runs`) per chunk,
+   and sums the partial int64 counts — bit-identical to the numpy
+   engine by construction, for any shard count.
 
 Shards execute as a thread pool (NumPy's sort/searchsorted release the
 GIL) sized by :func:`default_shard_count` — ``REPRO_TRACE_SHARDS`` if
@@ -339,49 +341,28 @@ def sharded_schedule_counts(fact: tuple, K: int, n_tiles: int,
     ``fact`` is ``(u_snd, u_rcv, u_new_src, mult_prefix)`` from
     :meth:`GraphTrace._pair_factorization`.  The factorization is split
     at new-sender boundaries (:func:`_segment_chunk_bounds`), each chunk
-    runs the same O(U) pass as the single-host engine — every chunk
-    start is a pair start in the global pass, so per-chunk
-    ``boundary[0] = True`` is exact, not an approximation — and the
-    partial per-tile bincounts are summed.  Integer counts throughout:
-    the result is bit-identical to the single-host engine for any shard
-    count.
+    runs the single-host pass (:func:`repro.core.trace.pair_runs`) over
+    its slice — every chunk start is a pair start in the global pass, so
+    the slices' runs are exactly the global runs — and the partial int64
+    counts are summed.  The result is bit-identical to the single-host
+    engine for any shard count.
     """
-    u_snd, u_rcv, u_new_src, mp = fact
-    U = int(u_snd.size)
+    from repro.core.trace import pair_runs
+
+    u_new_src = fact[2]
     halo = np.zeros(n_tiles, dtype=np.int64)
     remote_edges = np.zeros(n_tiles, dtype=np.int64)
-    if U == 0:
+    if u_new_src.size == 0:
         return halo, remote_edges
     if n_shards is None:
         n_shards = default_shard_count()
     bounds = _segment_chunk_bounds(u_new_src, int(n_shards))
-    Kd = u_rcv.dtype.type(K)
-
-    def one_chunk(se: tuple[int, int]) -> tuple[np.ndarray, np.ndarray]:
-        s, e = se
-        tile_u = u_rcv[s:e] // Kd
-        n = e - s
-        boundary = np.empty(n, dtype=bool)
-        boundary[0] = True
-        np.logical_or(u_new_src[s + 1:e], tile_u[1:] != tile_u[:-1],
-                      out=boundary[1:])
-        pidx = np.flatnonzero(boundary)
-        nxt = np.empty(pidx.size, dtype=np.int64)
-        nxt[:-1] = pidx[1:]
-        nxt[-1] = n
-        pair_tile = tile_u[pidx].astype(np.int64, copy=False)
-        pair_count = np.asarray(mp)[s + nxt] - np.asarray(mp)[s + pidx]
-        remote = (u_snd[s + pidx] // Kd) != tile_u[pidx]
-        h = np.bincount(pair_tile[remote], minlength=n_tiles)
-        # weighted bincount returns float64; multiplicities are ints
-        # < 2^53, so the partial (and its sum below) is exact
-        r = np.bincount(pair_tile[remote], weights=pair_count[remote],
-                        minlength=n_tiles)
-        return h.astype(np.int64, copy=False), r.astype(np.int64)
-
     chunks = list(zip(bounds[:-1].tolist(), bounds[1:].tolist()))
     n_workers = min(len(chunks), os.cpu_count() or 1)
-    for h, r in _map_shards(one_chunk, chunks, n_workers):
+    # Only the counts leave a chunk: its run arrays die with it.
+    for h, r in _map_shards(
+            lambda se: pair_runs(fact, K, n_tiles, *se)[:2], chunks,
+            n_workers):
         halo += h
         remote_edges += r
     return halo, remote_edges

@@ -358,7 +358,7 @@ def test_concurrent_resolve_single_flight():
 
 def test_concurrent_stat_bumps_exact():
     """The unguarded ``+=`` these locks replaced lost increments."""
-    from repro.core.trace import _bump_stat
+    from repro.trace_stats import _bump_stat
 
     reset_trace_stats()
     n_threads, n_iter = 8, 2000

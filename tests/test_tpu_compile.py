@@ -4,9 +4,9 @@ The TPU compiler is installed with jax and compiles for a topology that is
 described, not attached: what it refuses here it would refuse on the chip.
 Every case passes ``interpret=False`` explicitly, at the widths the system
 runs: GCN-Cora (``configs/gcn_cora``) on a Cora-sized graph padded to 2816
-nodes, the device schedule engine at 10^7 unique pairs and at
-ogbn-products' 123.7 M, and the Pallas histogram.  Nothing runs; results
-are checked on the chip by ``chip_smoke.py``.
+nodes, and the device schedule engine at 10^7 unique pairs and at
+ogbn-products' 123.7 M.  Nothing runs; results are checked on the chip by
+``chip_smoke.py``.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library, and under pytest-xdist every
@@ -146,19 +146,6 @@ def test_products_sweep_takes_the_mxu_histogram(one_chip):
     2,392 tiles at capacity 2^10 compile to the MXU histogram kernel."""
     hlo = _compile_schedule_engine(one_chip, 123_718_280, 2392)
     assert "tpu_custom_call" in hlo
-
-
-@pytest.mark.parametrize("n_pad", [128, 1024])
-def test_histogram_kernel_compiles(one_chip, n_pad):
-    block = sr.DEFAULT_BLOCK_EDGES
-    e_pad = 16 * block
-    compiled = _compile(
-        functools.partial(sr.padded_histogram, n_pad=n_pad,
-                          block_edges=block, interpret=False),
-        _spec(one_chip, (1, e_pad), jnp.int32),
-        _spec(one_chip, (1, e_pad)))
-    assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.out_info.shape == (1, n_pad)
 
 
 def test_fused_conformance_boundary_on_chip_program(one_chip):

@@ -2,10 +2,10 @@
 
 Load-bearing guarantees:
 
-* **Parity battery** — the amortized shared-factorization engine, the
-  jitted JAX engine, and the Pallas segment-reduce path produce schedule
-  quantities (vertex/edge/halo/cut counts, cache-hit data) **bit
-  identical** to the per-capacity PR-4 ``np.unique`` reference
+* **Parity battery** — the three engines over the shared factorization
+  (numpy, sharded, and the jitted JAX engine with its MXU histogram)
+  produce schedule quantities (vertex/edge/halo/cut counts, cache-hit
+  data) **bit identical** to the per-capacity PR-4 ``np.unique`` reference
   (``GraphTrace.schedule_reference``) across every registered trace
   dataset x a power-of-two capacity sweep, including the >= 100k-edge
   acceptance operating point;
@@ -63,7 +63,7 @@ def _no_disk_cache(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# Parity battery: amortized / jax / pallas engines == PR-4 reference.
+# Parity battery: numpy / sharded / jax engines == PR-4 reference.
 # ---------------------------------------------------------------------------
 def _reference_trace(name, trace):
     """The trace to run the PR-4 oracle on: ``power_law_sharded`` builds
@@ -75,12 +75,14 @@ def _reference_trace(name, trace):
     return resolve_trace_dataset("power_law_stream", DATASET_PARAMS[name])
 
 
+@pytest.mark.parametrize("engine", ["numpy", "sharded"])
 @pytest.mark.parametrize("name", sorted(DATASET_PARAMS))
-def test_amortized_engine_bitmatches_reference(name):
+def test_amortized_engine_bitmatches_reference(name, engine):
     trace = resolve_trace_dataset(name, DATASET_PARAMS[name])
     oracle = _reference_trace(name, trace)
+    trace.clear_schedules()   # each engine computes; no LRU entry is read
     for cap in _pow2_caps(trace.n_nodes):
-        new = trace.schedule(cap)
+        new = trace.schedule(cap, engine=engine)
         ref = oracle.schedule_reference(cap)
         for f in COUNT_FIELDS:
             np.testing.assert_array_equal(
@@ -121,44 +123,6 @@ def test_jax_engine_bitmatches_reference(name, sweep):
         # disk-less schedules still answer cache-hit queries (lazy pairs)
         np.testing.assert_array_equal(sched.cache_hit_fraction(0.2),
                                       ref.cache_hit_fraction(0.2))
-
-
-def test_pallas_segment_reduce_bitmatches_reference():
-    from repro.kernels import segment_reduce as sr
-
-    trace = resolve_trace_dataset("power_law", DATASET_PARAMS["power_law"])
-    u_snd, u_rcv, u_new_src, mp = trace._pair_factorization()
-    mult = np.diff(mp)
-    for cap in (64, 300, 1200):
-        ref = trace.schedule_reference(cap)
-        halo, cut = sr.schedule_counts_pallas(
-            u_snd, u_rcv, u_new_src, mult, ref.K, ref.n_tiles)
-        np.testing.assert_array_equal(
-            np.asarray(halo, np.float64), ref.halo_counts)
-        np.testing.assert_array_equal(
-            np.asarray(cut, np.float64), ref.remote_edge_counts)
-
-
-def test_pallas_tile_histogram_matches_bincount():
-    from repro.kernels import segment_reduce as sr
-
-    rng = np.random.default_rng(0)
-    ids = rng.integers(0, 37, size=5000).astype(np.int32)
-    w = rng.integers(0, 5, size=5000).astype(np.float32)
-    out = np.asarray(sr.tile_histogram(ids, w, 37), np.float64)
-    np.testing.assert_array_equal(out, np.bincount(ids, weights=w,
-                                                   minlength=37))
-    with pytest.raises(ValueError, match="equal-length"):
-        sr.tile_histogram(ids, w[:-1], 37)
-    # float32 exactness is guarded on the accumulated weight, not the
-    # edge count: few edges with huge multiplicities must be rejected
-    with pytest.raises(ValueError, match="float32"):
-        sr.tile_histogram(np.zeros(2, np.int32),
-                          np.full(2, 2.0**24, np.float32), 4)
-    # a one-hot block over the chip's VMEM is refused, not run elsewhere:
-    # 8192 tiles x 4096 edges x 4 B is exactly 128 MiB, one more is over
-    with pytest.raises(ValueError, match="VMEM"):
-        sr.tile_histogram(ids, w, 8192 + 1)
 
 
 def _mxu_and_scatter(ids, halo, cut, n_tiles_pad):
@@ -703,6 +667,55 @@ def test_schedule_counts_exact_at_2p53_boundary(total):
         assert [int(x) for x in sched.remote_edge_counts] == remote
         assert sched.cut_edges == sum(remote)
     assert sched.halo_total == sum(halo)
+
+
+@pytest.mark.parametrize("n_shards", [1, 2, 8])
+def test_sharded_counts_exact_at_2p53_boundary(n_shards, monkeypatch):
+    """The sharded engine's chunks accumulate cut edges in int64 like the
+    single-host pass: a 2^53-scale hot pair, wherever it sits and however
+    the factorization is split, gives the exact per-tile counts (and the
+    schedule one final float64 rounding of them)."""
+    from repro.distributed import trace_shard
+
+    V, cap, total = 96, 32, 2**53 + 4097
+    u_snd, u_rcv = _dense_pairs(V, seed=11)
+    U = u_snd.size
+    monkeypatch.setenv("REPRO_TRACE_SHARDS", str(n_shards))
+    for hot in (U // 4, U // 3, U // 2):
+        mult = np.ones(U, dtype=np.int64)
+        mult[hot] = total - (U - 1)
+        prefix = np.zeros(U + 1, dtype=np.int64)
+        np.cumsum(mult, out=prefix[1:])
+        trace = GraphTrace.from_factorization(V, u_snd, u_rcv, prefix)
+        _, remote, halo = _python_int_schedule_oracle(
+            u_snd, u_rcv, mult, V, cap)
+        got_halo, got_remote = trace_shard.sharded_schedule_counts(
+            trace._pair_factorization(), cap, -(-V // cap),
+            n_shards=n_shards)
+        assert [int(x) for x in got_remote] == remote, hot
+        assert [int(x) for x in got_halo] == halo, hot
+        sched = trace.schedule(cap, engine="sharded")
+        assert list(sched.remote_edge_counts) == [float(x) for x in remote]
+        assert [int(x) for x in sched.halo_counts] == halo
+
+
+def test_schedule_kernel_imports_no_analytical_core():
+    """The device kernel reads and writes only the leaf counter module:
+    importing it loads neither ``repro.core`` nor ``repro.core.trace``."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.pathsep.join([src,
+                                          os.environ.get("PYTHONPATH", "")])}
+    code = ("import sys, repro.kernels.segment_reduce; "
+            "print(sorted(m for m in ('repro.core', 'repro.core.trace') "
+            "if m in sys.modules))")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True)
+    assert out.stdout.strip() == "[]", out.stdout + out.stderr
 
 
 # ---------------------------------------------------------------------------
