@@ -57,6 +57,7 @@ from __future__ import annotations
 import functools
 import json
 import threading
+import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Callable, Mapping, Optional, Sequence
@@ -318,18 +319,69 @@ def _read_back(halo, cut, n_tiles):
     return halo[:n_tiles], cut[:n_tiles]
 
 
-def _jax_engine(fact, geos, n_pad):
-    """The pass on the device (:mod:`repro.kernels.segment_reduce`): one
-    upload of the factorization for the whole sweep, then per capacity
-    the dispatch when the generator is advanced and the read-back in the
-    thunk, so the host work of _assemble overlaps the device's.  The
-    sweep shares one compilation over ``n_pad`` padded tiles; the device
-    copy is dropped when the generator is."""
+#: The device engine's resident pair factorization: ``(weak reference to
+#: the host multiplicity prefix, device arrays)`` of the one trace used
+#: most recently, or None.  At most one per process; keyed on the host
+#: factorization's identity through the weak reference, so an evicted or
+#: collected trace keeps none of its host arrays alive and frees its
+#: device copy (see _release_resident).
+_RESIDENT: Optional[tuple] = None
+
+
+def _release_resident(trace=None) -> None:
+    """Drop the resident device factorization: whichever it is, or only
+    if it is ``trace``'s (a typed trace's: one of its relations')."""
+    global _RESIDENT
+    slot = _RESIDENT
+    if slot is None:
+        return
+    if trace is not None:
+        mp = slot[0]()
+        parts = (trace._relation_traces.values()
+                 if isinstance(trace, TypedGraphTrace) else (trace,))
+        if not any(t._fact is not None and t._fact[3] is mp for t in parts):
+            return
+    _RESIDENT = None
+
+
+def _release_collected(ref: weakref.ref) -> None:
+    """Weak-reference callback: the resident copy's host factorization
+    was collected."""
+    global _RESIDENT
+    slot = _RESIDENT
+    if slot is not None and slot[0] is ref:
+        _RESIDENT = None
+
+
+def _device_factorization(fact: tuple) -> tuple:
+    """``fact`` on the device: the resident copy when it is this
+    factorization's, else released and replaced by a fresh upload
+    (int32 multiplicities, 13 B a pair)."""
+    global _RESIDENT
+    u_snd, u_rcv, u_new_src, mp = fact
+    slot = _RESIDENT
+    if slot is not None and slot[0]() is mp:
+        _bump_stat("schedule_factorization_reuses")
+        return slot[1]
+    _RESIDENT = slot = None   # the old copy goes before the new one comes
     from repro.kernels import segment_reduce
 
-    u_snd, u_rcv, u_new_src, mp = fact
     dev = segment_reduce.upload_factorization(u_snd, u_rcv, u_new_src,
                                               np.diff(mp))
+    _RESIDENT = (weakref.ref(mp, _release_collected), dev)
+    return dev
+
+
+def _jax_engine(fact, geos, n_pad):
+    """The pass on the device (:mod:`repro.kernels.segment_reduce`) over
+    the resident factorization (uploaded by the first call on a trace,
+    reused by every later one), then per capacity the dispatch when the
+    generator is advanced and the read-back in the thunk, so the host
+    work of _assemble overlaps the device's.  The sweep shares one
+    compilation over ``n_pad`` padded tiles."""
+    from repro.kernels import segment_reduce
+
+    dev = _device_factorization(fact)
     for _, n_tiles, K in geos:
         halo, cut = segment_reduce.schedule_counts(*dev, K, n_pad)
         yield functools.partial(_read_back, halo, cut, n_tiles)
@@ -1159,8 +1211,9 @@ def _evict_to_budget() -> None:
     sizes = {k: t.nbytes for k, t in _TRACE_CACHE.items()}
     total = sum(sizes.values())
     while len(_TRACE_CACHE) > 1 and total > _TRACE_CACHE_BUDGET_BYTES:
-        key, _ = _TRACE_CACHE.popitem(last=False)
+        key, trace = _TRACE_CACHE.popitem(last=False)
         total -= sizes[key]
+        _release_resident(trace)
 
 
 def _trace_cache_insert(key: tuple, trace: GraphTrace) -> None:
@@ -1189,7 +1242,9 @@ def trace_cache_info() -> dict:
     span totals: ``schedules_ns`` (whole ``schedule``/``schedules``
     calls), ``schedule_upload_ns`` and ``schedule_upload_bytes``, the
     pair factorization's transfers ``schedule_factorization_uploads``
-    (one per device-engine call, whatever its capacities),
+    (one per device-engine call that finds another trace's, or none,
+    resident) and ``schedule_factorization_reuses`` (one per call that
+    finds its own),
     ``schedule_dispatch_ns`` and ``schedule_wait_ns``, and the histogram
     path per capacity, ``schedule_hist_mxu`` / ``schedule_hist_scatter``
     (device engine)."""
@@ -1276,12 +1331,14 @@ def clear_trace_cache() -> None:
 
     Also clears each cached trace's per-capacity schedule LRU, so a
     service holding an external reference to a trace does not keep the
-    schedule memory alive through this call.
+    schedule memory alive through this call, and releases the device
+    engine's resident factorization.
     """
     with _CACHE_LOCK:
         for trace in list(_TRACE_CACHE.values()):
             trace.clear_schedules()
         _TRACE_CACHE.clear()
+        _release_resident()
 
 
 def _power_law_trace(*, n_nodes, n_edges, seed=0, alpha=1.6) -> GraphTrace:

@@ -12,8 +12,9 @@ destination tiles.  This module runs that pass on the accelerator; it is
 the count function of ``engine="jax"`` in :mod:`repro.core.trace`, which
 imports it lazily, and the numpy engine stays the default.
 
-* :func:`upload_factorization` moves the factorization to the device
-  once for a whole capacity sweep.
+* :func:`upload_factorization` moves the factorization to the device;
+  the caller keeps the device arrays for as many sweeps as it likes
+  (``core/trace`` keeps one trace's resident across calls).
 * :func:`schedule_counts` is the per-capacity entry: XLA computes the
   tile ids and the halo / cut-weight flags elementwise, then both
   histograms are built either by the two-level one-hot MXU kernel (tile
@@ -185,9 +186,9 @@ def _schedule_counts_jnp(u_snd, u_rcv, u_new_src, mult, K, n_tiles_pad,
 def upload_factorization(u_snd, u_rcv, u_new_src, mult):
     """The pair factorization as device arrays, multiplicities as int32.
 
-    One transfer serves every capacity of a sweep: pass the result to
-    :func:`schedule_counts` for each.  Timed as the
-    ``repro.trace.schedule.upload`` span; the bytes are counted in
+    One transfer serves every capacity passed with the result to
+    :func:`schedule_counts`, for as long as the caller keeps it.  Timed
+    as the ``repro.trace.schedule.upload`` span; the bytes are counted in
     ``schedule_upload_bytes`` and the transfer itself in
     ``schedule_factorization_uploads``.
     """

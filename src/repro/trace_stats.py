@@ -34,6 +34,7 @@ _TRACE_STATS = {
     "schedule_upload_ns": 0,     # jax engine: operands to the device
     "schedule_upload_bytes": 0,  # jax engine: nbytes of those operands
     "schedule_factorization_uploads": 0,  # jax engine: pair factorization
+    "schedule_factorization_reuses": 0,   # jax engine: found resident
     "schedule_dispatch_ns": 0,   # jax engine: enqueueing the program
     "schedule_wait_ns": 0,       # jax engine: device compute + read-back
     "schedule_hist_mxu": 0,      # jax engine: capacities on the MXU kernel

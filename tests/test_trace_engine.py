@@ -745,10 +745,12 @@ def test_schedule_spans_and_upload_counters(engine):
     after = trace_cache_info()["stats"]
     d = {k: after[k] - before[k] for k in after}
     on_device = engine == "jax"
-    # Once per sweep: int32 senders, receivers and multiplicities and the
-    # bool new-sender mask (13 B per pair); per capacity the int32 stride.
+    # First call on the trace: int32 senders, receivers and multiplicities
+    # and the bool new-sender mask (13 B per pair); per capacity the int32
+    # stride.
     assert d["schedule_upload_bytes"] == on_device * (13 * U + 4 * len(caps))
     assert d["schedule_factorization_uploads"] == on_device
+    assert d["schedule_factorization_reuses"] == 0
     for key in _DEVICE_SPAN_COUNTERS:
         assert (d[key] > 0) == on_device, key
     assert d["schedules_ns"] >= sum(d[k] for k in _DEVICE_SPAN_COUNTERS)
@@ -756,6 +758,14 @@ def test_schedule_spans_and_upload_counters(engine):
     # 96 vertices: every capacity's tile count is on the MXU side.
     assert d["schedule_hist_mxu"] == on_device * len(caps)
     assert d["schedule_hist_scatter"] == 0
+    # A second call on the same trace finds the factorization resident:
+    # only the strides move.
+    trace.clear_schedules()
+    _, d = _stat_delta(lambda: trace.schedules(caps, engine=engine))
+    assert d["schedule_upload_bytes"] == on_device * 4 * len(caps)
+    assert d["schedule_factorization_uploads"] == 0
+    assert d["schedule_factorization_reuses"] == on_device
+    assert d["schedule_computes"] == len(caps)
 
 
 @pytest.mark.parametrize("above", [False, True], ids=["at_T", "above_T"])
@@ -792,7 +802,8 @@ def test_schedule_spans_nest_on_the_profiler_timeline(tmp_path):
     from jax.profiler import ProfileData
 
     trace = _simple_trace()
-    trace.schedules([8], engine="jax")    # compile outside the profile
+    # Compile outside the profile; the factorization stays resident.
+    trace.schedules([8], engine="jax")
     trace.clear_schedules()
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -808,12 +819,11 @@ def test_schedule_spans_nest_on_the_profiler_timeline(tmp_path):
     outer = [s for s in spans if s[0] == "repro.trace.schedules"]
     inner = [s for s in spans if s[0].startswith("repro.trace.schedule.")]
     assert len(outer) == 1
-    # One upload of the factorization, then per capacity the stride's
+    # No upload of the resident factorization; per capacity the stride's
     # upload, the dispatch and the wait.
     assert sorted(s[0] for s in inner) == sorted(
-        ["repro.trace.schedule.upload"]
-        + 2 * ["repro.trace.schedule.upload", "repro.trace.schedule.dispatch",
-               "repro.trace.schedule.wait"])
+        2 * ["repro.trace.schedule.upload", "repro.trace.schedule.dispatch",
+             "repro.trace.schedule.wait"])
     _, lo, hi = outer[0]
     assert all(lo <= start and end <= hi for _, start, end in inner)
 
@@ -827,19 +837,28 @@ def _stat_delta(fn):
 
 @pytest.mark.parametrize("call", ["sweep", "single"])
 def test_factorization_uploads_once_per_call(call):
-    """The device engine moves the pair factorization once per call and
-    serves every capacity of the call from that copy."""
+    """The device engine moves the pair factorization at most once per
+    call, and only on the trace's first call: every capacity of that call
+    and every later call is served from the resident copy."""
     trace = _simple_trace()
     trace._pair_factorization()
     caps = [8, 16, 32] if call == "sweep" else [8]
-    if call == "sweep":
-        _, d = _stat_delta(lambda: trace.schedules(caps, engine="jax"))
-    else:
-        _, d = _stat_delta(lambda: trace.schedule(caps[0], engine="jax"))
-    assert d["schedule_factorization_uploads"] == 1
-    assert d["schedule_computes"] == len(caps)
+
+    def run():
+        trace.clear_schedules()
+        if call == "sweep":
+            return trace.schedules(caps, engine="jax")
+        return trace.schedule(caps[0], engine="jax")
+
+    for uploads in (1, 0):
+        _, d = _stat_delta(run)
+        assert d["schedule_factorization_uploads"] == uploads
+        assert d["schedule_factorization_reuses"] == 1 - uploads
+        assert d["schedule_computes"] == len(caps)
+    trace.clear_schedules()
     _, d = _stat_delta(lambda: trace.schedules(caps, engine="numpy"))
     assert d["schedule_factorization_uploads"] == 0
+    assert d["schedule_factorization_reuses"] == 0
 
 
 @pytest.mark.parametrize("above", [False, True], ids=["mxu", "scatter"])
@@ -867,3 +886,91 @@ def test_schedule_counts_on_device_arrays_moves_only_the_stride(above):
         assert d["schedule_factorization_uploads"] == 1
         for got, want in zip(dev, host):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# The resident factorization: one per process, the most recently used
+# trace's, released with its trace.
+# ---------------------------------------------------------------------------
+
+def _resident_is(trace) -> bool:
+    slot = trace_mod._RESIDENT
+    return slot is not None and slot[0]() is trace._pair_factorization()[3]
+
+
+@pytest.mark.parametrize("path", ["mxu", "scatter"])
+def test_second_sweep_reuses_the_resident_factorization(path):
+    """A second sweep on a trace uploads only its strides, bumps
+    ``schedule_factorization_reuses`` once, still computes every capacity,
+    and equals the numpy engine entry for entry on either histogram
+    path."""
+    from repro.kernels import segment_reduce as sr
+
+    if path == "mxu":
+        trace, caps = _simple_trace(), [8, 16, 32]
+    else:
+        V = sr.MXU_HIST_MAX_TILES + 100
+        rng = np.random.default_rng(11)
+        trace = GraphTrace(rng.integers(0, V, 3000), rng.integers(0, V, 3000),
+                           V)
+        caps = [1, 4096]           # V padded tiles: the scatter-adds
+    trace.schedules(caps, engine="jax")
+    assert _resident_is(trace)
+    trace.clear_schedules()
+    got, d = _stat_delta(lambda: trace.schedules(caps, engine="jax"))
+    assert d["schedule_upload_bytes"] == 4 * len(caps)
+    assert d["schedule_factorization_uploads"] == 0
+    assert d["schedule_factorization_reuses"] == 1
+    assert d["schedule_computes"] == len(caps)
+    assert d[f"schedule_hist_{path}"] == len(caps)
+    assert d["schedule_cache_hits"] == d["schedule_disk_hits"] == 0
+    trace.clear_schedules()
+    want = trace.schedules(caps, engine="numpy")
+    for cap, a, b in zip(caps, got, want):
+        for f in COUNT_FIELDS:
+            np.testing.assert_array_equal(getattr(a, f), getattr(b, f),
+                                          err_msg=f"cap={cap} field={f}")
+
+
+@pytest.mark.parametrize("how", ["other_trace", "clear_trace_cache",
+                                 "evicted", "collected"])
+def test_resident_factorization_is_released(how):
+    """At most one factorization stays on the device: a call on another
+    trace replaces it (the first trace's next call uploads again), and
+    ``clear_trace_cache``, eviction from the resolved-trace cache and
+    garbage collection of its trace release it."""
+    import gc
+
+    clear_trace_cache()
+    old_budget = trace_cache_info()["budget_bytes"]
+    params = {"n_nodes": 300, "n_edges": 1500}
+    try:
+        first = resolve_trace_dataset("power_law", {**params, "seed": 1})
+        first.schedules([16], engine="jax")
+        assert _resident_is(first)
+        if how == "other_trace":
+            other = _simple_trace()
+            other.schedules([16], engine="jax")
+            assert _resident_is(other) and not _resident_is(first)
+            first.clear_schedules()
+            _, d = _stat_delta(lambda: first.schedules([16], engine="jax"))
+            assert d["schedule_factorization_uploads"] == 1
+            assert d["schedule_factorization_reuses"] == 0
+            assert _resident_is(first)
+        else:
+            if how == "clear_trace_cache":
+                clear_trace_cache()
+            elif how == "evicted":
+                set_trace_cache_budget(1)  # the newest entry alone survives
+                resolve_trace_dataset("power_law", {**params, "seed": 2})
+                assert trace_cache_info()["entries"] == 1
+            else:
+                lone = _simple_trace()     # held by nothing but this frame
+                lone.schedules([16], engine="jax")
+                assert _resident_is(lone)
+                del lone
+                gc.collect()
+            assert trace_mod._RESIDENT is None
+    finally:
+        set_trace_cache_budget(old_budget)
+        clear_trace_cache()

@@ -458,6 +458,7 @@ def test_reset_trace_stats_zeroes_all_counters():
                           "trace_builds", "schedules_ns",
                           "schedule_upload_ns", "schedule_upload_bytes",
                           "schedule_factorization_uploads",
+                          "schedule_factorization_reuses",
                           "schedule_dispatch_ns", "schedule_wait_ns",
                           "schedule_hist_mxu", "schedule_hist_scatter"}
     assert all(v == 0 for v in stats.values())
